@@ -74,6 +74,7 @@ from repro.core.search_pool import (TASKS_PER_WORKER,            # noqa: E402
                                     _run_subspace, partition_space)
 from repro.runtime import chaos                                  # noqa: E402
 from repro.runtime.fault_tolerance import PreemptionGuard        # noqa: E402
+from repro.utils.compile_cache import enable_compile_cache       # noqa: E402
 
 try:                                                             # noqa: E402
     from busyloop import measure_busyloop_rate, measure_parallel_capacity
@@ -934,6 +935,7 @@ def main() -> None:
                          "--smoke for the CI-sized slice)")
     ap.add_argument("-o", "--output", default="BENCH_compile.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.chaos:
         if "fork" not in _mp.get_all_start_methods():

@@ -62,7 +62,9 @@ Scheduling-only (wall clock / resilience / post-checks; excluded from
 ``workers``
     ``1`` (default) searches serially in-process; ``N > 1`` farms
     disjoint sub-spaces over a process pool
-    (``core/search_pool.py``); ``None`` uses ``os.cpu_count()``.
+    (``core/search_pool.py``); ``None`` uses ``os.cpu_count()``.  Only
+    host engines fan out: an engine that runs jax stays in the calling
+    process, which holds the device.
 ``batch_size``
     Cut tuples priced per ``CutpointEngine.score_batch`` call
     (``1`` falls back to the per-tuple loop).  An ``@N`` suffix on

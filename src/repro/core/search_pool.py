@@ -75,11 +75,20 @@ failure classes:
   duplicate is degraded through
   :func:`repro.core.options.degrade_engine` to the ``"journal"`` engine
   so a hanging device backend cannot hang its own rescue.
-* **Device-engine degradation** -- a worker whose ``engine="device"``
-  (or ``"pipeline"``) scoring raises falls back to the journal engine
-  *inside the task* and reports a ``device_fallback`` event; results are
+* **Device-engine degradation** -- a task whose ``engine="device"``
+  (or ``"pipeline"``) scoring raises a *transient* error (one carrying
+  ``transient = True``) falls back to the journal engine *inside the
+  task* and reports a ``device_fallback`` event; results are
   bit-identical by the engine contract, so degradation is logged, never
-  silent.
+  silent.  A deterministic device error (a kernel that fails to compile,
+  a shape error) propagates like any other deterministic exception.
+
+Engines that run jax (``pipeline:lax``, ``pipeline:pallas``,
+``device:scan``, ``device:pallas``) never enter the process pool: a chip
+belongs to one process, and the caller already holds it.  Their search
+runs in the calling process (spread over every visible device by the
+pipeline's ``shard_map`` path), and ``workers`` fans out host engines
+only.
 
 Every recovery is surfaced as a :class:`FaultEvent` on
 ``SearchResult.events`` (retry / straggler / device_fallback / resume) --
@@ -108,11 +117,10 @@ import itertools
 import multiprocessing as mp
 import os
 import pickle
-import sys
 import time
-import warnings
 from collections import deque
-from concurrent.futures import FIRST_COMPLETED, ProcessPoolExecutor, wait
+from concurrent.futures import (FIRST_COMPLETED, Future,
+                                ProcessPoolExecutor, wait)
 from concurrent.futures.process import BrokenProcessPool
 from dataclasses import dataclass
 
@@ -180,7 +188,7 @@ def _worker_engine(token: tuple, payload: bytes,
     if engine is None:
         # a new search token invalidates engines of previous searches
         for old in [k for k in _ENGINES if k[0] != token]:
-            del _ENGINES[old]
+            _ENGINES.pop(old, None)
         gg, hw = pickle.loads(payload)
         engine = _ENGINES[key] = _cp.CutpointEngine(gg, hw, backend=backend,
                                                     engine=engine_spec)
@@ -188,30 +196,26 @@ def _worker_engine(token: tuple, payload: bytes,
 
 
 def _engine_needs_jax(spec) -> bool:
-    """Whether worker processes will execute jax for this engine spec.
+    """Whether this engine spec runs jax, and so must run in the process
+    that holds the device.
 
     Exactly the variants whose scoring path jits: the journal engine and
-    the numpy reference variants never import jax in the worker."""
+    the numpy reference variants never touch jax."""
     return ((spec.name == "pipeline" and spec.variant in ("lax", "pallas"))
             or (spec.name == "device" and spec.variant in ("scan", "pallas")))
 
 
-def _spawn_main_viable() -> bool:
-    """Whether spawn-started workers can initialize.
-
-    ``multiprocessing``'s spawn path re-imports the parent's ``__main__``
-    in the child (unless the parent is ``python -c``/embedded, where it
-    skips the step).  A parent fed from stdin records ``<stdin>`` as its
-    main path, which the child then fails to open -- every worker dies at
-    startup.  Detect that corner so the caller can degrade gracefully."""
-    main = sys.modules.get("__main__")
-    if main is None or getattr(getattr(main, "__spec__", None),
-                               "name", None):
-        return True                      # python -m style: import by name
-    if sys.argv[0] in ("", "-c"):
-        return True                      # spawn skips main re-import
-    path = getattr(main, "__file__", None)
-    return path is None or os.path.exists(path)
+def _run_inline(fn, *args) -> Future:
+    """Run one task now, in the calling process, as an already-settled
+    future -- how jax-engine searches dispatch, since their device
+    belongs to this process.  The dispatch loop (journal, transient
+    retry, preemption) treats it like a pool future."""
+    fut: Future = Future()
+    try:
+        fut.set_result(fn(*args))
+    except Exception as e:
+        fut.set_exception(e)
+    return fut
 
 
 def _maybe_fail(key, attempt: int = 0) -> None:
@@ -238,9 +242,9 @@ def _run_subspace(task, attempt: int = 0):
     replay; the argmin is ``None`` only when the *entire* task falls to
     the incumbent, which is safe because the global optimum's own task
     can never prune it (its bound never exceeds any incumbent).  A
-    failing device/pipeline engine degrades to the journal engine
-    in-task (bit-identical by contract) and reports a
-    ``device_fallback`` event instead of failing the task.
+    device/pipeline engine that raises a transient error degrades to the
+    journal engine in-task (bit-identical by contract) and reports a
+    ``device_fallback`` event; deterministic errors propagate.
     """
     (token, payload, prefix, suffix_dims, objective, batch_size, engine_spec,
      backend) = task[:8]
@@ -264,10 +268,10 @@ def _run_subspace(task, attempt: int = 0):
             _chaos.maybe_fire("device", prefix, attempt)
         best, n, pruned = score(engine)
     except Exception as e:
-        if engine_name == "journal":
+        if engine_name == "journal" or not getattr(e, "transient", False):
             raise
-        # device/pipeline engine raised: degrade to the journal engine --
-        # logged, never silent, and bit-identical by the engine contract
+        # transient device/pipeline failure: degrade to the journal
+        # engine -- logged, never silent, bit-identical by contract
         engine = _worker_engine(token, payload, degrade_engine(engine_spec),
                                 backend)
         best, n, pruned = score(engine)
@@ -304,7 +308,7 @@ def _run_descent(task, attempt: int = 0):
             _chaos.maybe_fire("device", start, attempt)
         cur, visited = run(engine)
     except Exception as e:
-        if engine_name == "journal":
+        if engine_name == "journal" or not getattr(e, "transient", False):
             raise
         engine = _worker_engine(token, payload, degrade_engine(engine_spec),
                                 backend)
@@ -399,14 +403,10 @@ class ParallelSearchDriver:
     mp_context:
         ``multiprocessing`` start method.  Default: ``"fork"`` where
         available (workers inherit the parent's imports, so startup is
-        milliseconds), else the platform default.  When a search's
-        engine runs jax inside the workers (``pipeline:lax``,
-        ``pipeline:pallas``, ``device:scan``, ``device:pallas``) the
-        defaulted context is ratcheted to ``"spawn"`` before the pool is
-        (re)created -- forking a parent that has already run jit'd code
-        hands the children XLA's locked mutexes and deadlocks them (see
-        :meth:`_ensure_jax_safe_pool`).  Passing ``mp_context``
-        explicitly disables the ratchet.
+        milliseconds), else the platform default.  Workers only ever run
+        host engines: a search whose engine runs jax (``pipeline:lax``,
+        ``pipeline:pallas``, ``device:scan``, ``device:pallas``) stays
+        in the calling process, which holds the device.
     max_retries:
         Re-dispatch budget per task for *transient* failures (a dead
         worker process breaking the pool, an injected ``ChaosError``, a
@@ -439,7 +439,6 @@ class ParallelSearchDriver:
                  guard: "PreemptionGuard | None" = None,
                  straggler_threshold: float = 4.0):
         self.workers = max(1, workers or os.cpu_count() or 1)
-        self._explicit_ctx = mp_context is not None
         if mp_context is None and "fork" in mp.get_all_start_methods():
             mp_context = "fork"
         self._ctx = mp.get_context(mp_context) if mp_context else None
@@ -456,45 +455,6 @@ class ParallelSearchDriver:
             self._pool = ProcessPoolExecutor(max_workers=self.workers,
                                              mp_context=self._ctx)
         return self._pool
-
-    def _jax_safe_opts(self, opts):
-        """Make a search with jax-in-worker engines fork-safe.
-
-        XLA's runtime is multithreaded the moment the parent evaluates
-        anything under jit; fork-started children then inherit its locked
-        mutexes and deadlock on first device call.  Engines whose workers
-        stay in numpy (journal, device:reference, pipeline:reference) are
-        unaffected and keep fork's millisecond startup.  For jax-running
-        specs the defaulted fork context is ratcheted to spawn -- one-way
-        for the life of the driver, since spawn is safe for every engine
-        and flip-flopping would churn worker pools (and their per-process
-        engine caches) on mixed-engine drivers.  When spawn cannot
-        reconstruct the parent's ``__main__`` (a stdin-fed script), the
-        engine degrades to the journal replay instead -- bit-identical by
-        the replay contract, so it only costs wall clock -- with a loud
-        warning.  An explicit ``mp_context`` from the caller is always
-        honored, including its deadlock hazard.
-        """
-        if self._explicit_ctx or not _engine_needs_jax(opts.engine_spec()):
-            return opts
-        if self._ctx is not None and self._ctx.get_start_method() != "fork":
-            return opts
-        if "spawn" not in mp.get_all_start_methods():  # pragma: no cover
-            return opts
-        if not _spawn_main_viable():
-            warnings.warn(
-                f"engine={opts.engine!r} runs jax inside worker processes, "
-                f"which is unsafe under the fork start method once the "
-                f"parent has used jax -- and spawn cannot re-import this "
-                f"process's __main__ ({getattr(sys, 'argv', ['?'])[0]!r}). "
-                f"Falling back to the (bit-identical) journal engine for "
-                f"worker tasks; run from an importable script/module or "
-                f"pass mp_context explicitly to silence this.",
-                RuntimeWarning, stacklevel=3)
-            return opts.replace(engine=degrade_engine(opts.engine))
-        self._reset()
-        self._ctx = mp.get_context("spawn")
-        return opts
 
     def map(self, fn, items, chunksize: int = 1) -> list:
         """Ordered parallel map (the generic face of the pool).
@@ -553,9 +513,11 @@ class ParallelSearchDriver:
 
     def _run_tasks(self, fn, tasks: list, keys: list, events: list,
                    journal=None, encode=None, decode=None, degrade=None,
-                   prepare=None, observe=None):
+                   prepare=None, observe=None, inline: bool = False):
         """Dispatch ``tasks`` with retry, healing, deadlines, journaling
         and preemption drain; returns worker results in task order.
+        ``inline`` runs every task in the calling process instead of the
+        pool (engines that run jax, see :func:`_engine_needs_jax`).
 
         Correctness rests on task purity: ``fn(tasks[i])`` always returns
         the same value, so journal replays, bounded re-dispatch after a
@@ -602,17 +564,23 @@ class ParallelSearchDriver:
         # cap in-flight submissions: a pool break then only blames the
         # tasks actually handed to the broken pool, and preemption drains
         # quickly
-        window = max(1, 2 * self.workers)
+        # (inline: one at a time, so each task inherits the incumbent of
+        # every task before it)
+        window = 1 if inline else max(1, 2 * self.workers)
 
         def submit(i: int) -> None:
             if prepare is not None:          # inject the live incumbent at
                 live[i] = prepare(live[i])   # submit time (also on retries)
-            try:
-                fut = self._executor().submit(fn, live[i], attempts[i])
-            except BrokenProcessPool:        # broke between loop ticks
-                self._reset()
-                fut = self._executor().submit(fn, live[i], attempts[i])
-            inflight[fut] = (i, time.monotonic(), attempts[i])
+            t0 = time.monotonic()
+            if inline:
+                fut = _run_inline(fn, live[i], attempts[i])
+            else:
+                try:
+                    fut = self._executor().submit(fn, live[i], attempts[i])
+                except BrokenProcessPool:    # broke between loop ticks
+                    self._reset()
+                    fut = self._executor().submit(fn, live[i], attempts[i])
+            inflight[fut] = (i, t0, attempts[i])
 
         def fill() -> None:
             while pending and len(inflight) < window:
@@ -767,11 +735,14 @@ class ParallelSearchDriver:
         for r in runs:
             space *= len(r) + 1
         exhaustive = space <= opts.exhaustive_limit
-        serial_ok = (self.workers <= 1 or not runs
+        inline = _engine_needs_jax(opts.engine_spec())
+        serial_ok = (self.workers <= 1 or not runs or inline
                      or (exhaustive and space < min_parallel_space))
         if not runs or (serial_ok and opts.resume_dir is None):
             # workers=1 + resume_dir=None keeps cutpoint.search on its
-            # serial path (it would otherwise bounce back to a driver)
+            # serial path (it would otherwise bounce back to a driver);
+            # jax engines take it at any worker count: they run in this
+            # process, sharded over its devices by the pipeline itself
             return _cp.search(
                 gg, hw, opts.replace(workers=1, resume_dir=None),
                 warm_start=warm_start)
@@ -797,13 +768,13 @@ class ParallelSearchDriver:
             journal = self._open_journal(opts.resume_dir, payload, opts,
                                          "descent", tuple(starts))
         batch_size = opts.engine_spec().batch_size
-        opts = self._jax_safe_opts(opts)
         tasks = [(token, payload, s, opts.objective, batch_size,
                   opts.engine, opts.backend) for s in starts]
         results = self._run_tasks(
             _run_descent, tasks, keys=starts, events=events,
             journal=journal, encode=_encode_descent,
-            decode=_decode_descent, degrade=_degrade_descent)
+            decode=_decode_descent, degrade=_degrade_descent,
+            inline=inline)
         visited: set = set()
         best = None
         for start, (m, seen, wev) in zip(starts, results):
@@ -838,9 +809,8 @@ class ParallelSearchDriver:
         under ``count_pruned`` the ``evaluated`` accounting is identical
         to a cold run.
         """
-        opts = self._jax_safe_opts(
-            _cp.resolve_options(options, legacy,
-                                site="driver.run_subspaces"))
+        opts = _cp.resolve_options(options, legacy,
+                                   site="driver.run_subspaces")
         objective = opts.objective
         if blocks is None:
             blocks = _cp.split_blocks(gg)
@@ -888,7 +858,8 @@ class ParallelSearchDriver:
             journal=journal, encode=_encode_subspace,
             decode=_decode_subspace, degrade=_degrade_subspace,
             prepare=_prepare if opts.prune else None,
-            observe=_observe if opts.prune else None)
+            observe=_observe if opts.prune else None,
+            inline=_engine_needs_jax(opts.engine_spec()))
         evaluated = 0
         pruned_total = 0
         for prefix, (_m, nev, npr, wev) in zip(prefixes, results):

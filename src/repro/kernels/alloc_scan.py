@@ -530,6 +530,10 @@ if HAVE_JAX:
             return jnp.sum(jnp.where(lane == j, x, 0), axis=1,
                            keepdims=True)
 
+        def col3(x, j):                      # lane j of a LANES-wide row
+            return jnp.sum(jnp.where(l3 == j, x, 0), axis=1,
+                           keepdims=True)
+
         side_g = is_side_s[t] > 0
         main_g = main_s[t]
         sc_g = sc_s[t]
@@ -552,13 +556,13 @@ if HAVE_JAX:
         mloc = colv(loc, main_g)                       # (B, 1)
         main_in_buf = mloc < NB
         read_bytes = jnp.zeros((block_b, 1), jnp.int32)
-        in_buf = jnp.zeros((block_b, LANES), bool)     # lanes 0..2 used
+        in_buf = l3 < 0                                # lanes 0..2 used
         for j in range(k):
             sl = colv(loc, gin_s[t, j])
             read_bytes += jnp.where(sl == LOC_DRAM, srcsz_s[t, j], 0)
             in_buf = in_buf | (sl == l3)
-        free = jnp.where(l3 < NB, (live == LIVE_EMPTY), False)
-        f0, f1, f2 = colv(free, 0) > 0, colv(free, 1) > 0, colv(free, 2) > 0
+        free = (l3 < NB) & (live == LIVE_EMPTY)
+        f0, f1, f2 = col3(free, 0) > 0, col3(free, 1) > 0, col3(free, 2) > 0
         fetch_b = jnp.where(f0, 0, jnp.where(f1, 1, jnp.where(f2, 2, -1)))
         need_fetch = jnp.logical_not(main_in_buf) & (fetch_b >= 0)
         cond_in = fr & ((main_in_buf & (mloc == l3))
@@ -603,7 +607,7 @@ if HAVE_JAX:
         wrf_new = jnp.maximum(wrf_new, jnp.where(addf, wrc_g, 0))
 
         ofree = free & jnp.logical_not(in_buf)
-        o0, o1, o2 = colv(ofree, 0) > 0, colv(ofree, 1) > 0, colv(ofree, 2) > 0
+        o0, o1, o2 = col3(ofree, 0) > 0, col3(ofree, 1) > 0, col3(ofree, 2) > 0
         b_out = jnp.where(o0, 0, jnp.where(o1, 1, jnp.where(o2, 2, -1)))
         main_live = jnp.sum(jnp.where((mloc == l3) & (live == main_g),
                                       1, 0), axis=1, keepdims=True) > 0
@@ -693,16 +697,11 @@ if HAVE_JAX:
         fn = _ALLOC_CALL_CACHE[key] = jax.jit(call)
         return fn
 
-    def alloc_scan_pallas(t: AllocScanTables, frame: np.ndarray,
-                          interpret: bool | None = None,
-                          block_b: int = 256) -> AllocScanResult:
-        """Pallas replay; bit-identical integers to the numpy reference.
-
-        ``interpret=None`` auto-selects: compiled on TPU hosts, Pallas
-        interpret mode elsewhere (same kernel body, jax-evaluated)."""
-        from repro.kernels.score_batch import _on_tpu
-        if interpret is None:
-            interpret = not _on_tpu()
+    def _alloc_operands(t: AllocScanTables, frame: np.ndarray,
+                        block_b: int):
+        """The kernel's operands for a (B, G) frame batch -- ten SMEM
+        step tables, the padded frame tile and the initial ``rem``/``loc``
+        rows -- with its grid geometry ``(nb, block_b, lanes)``."""
         b = frame.shape[0]
         n = t.n
         lanes = _pad_up(n + 2, LANES)
@@ -721,9 +720,23 @@ if HAVE_JAX:
                    t.in_size.astype(i32), t.out_size.astype(i32),
                    np.pad(t.wr_cand, (0, lanes - (n + 2))).astype(i32),
                    t.spill_ok.astype(i32))
-        fn = _build_alloc_call(bp // block_b, n, t.k, block_b, lanes,
-                               interpret)
-        io, stats = fn(*scalars, fp, rem0, loc0)
+        return scalars + (fp, rem0, loc0), (bp // block_b, block_b, lanes)
+
+    def alloc_scan_pallas(t: AllocScanTables, frame: np.ndarray,
+                          interpret: bool | None = None,
+                          block_b: int = 256) -> AllocScanResult:
+        """Pallas replay; bit-identical integers to the numpy reference.
+
+        ``interpret=None`` auto-selects: compiled on TPU hosts, Pallas
+        interpret mode elsewhere (same kernel body, jax-evaluated)."""
+        from repro.kernels.score_batch import _on_tpu
+        if interpret is None:
+            interpret = not _on_tpu()
+        b = frame.shape[0]
+        n = t.n
+        args, (nb, block_b, lanes) = _alloc_operands(t, frame, block_b)
+        fn = _build_alloc_call(nb, n, t.k, block_b, lanes, interpret)
+        io, stats = fn(*args)
         io = np.asarray(io, dtype=np.int64)
         stats = np.asarray(stats, dtype=np.int64)
         return AllocScanResult(

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 
 def _act(name: str, x):
@@ -105,7 +104,7 @@ def fused_block(x, scale, w_gate, w_up, w_down, post_scale=None, *,
             pltpu.VMEM((bm, d), x.dtype),                     # normalized x
             pltpu.VMEM((bm, d), jnp.float32),                 # accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, scale, w_gate, w_up, w_down, post_scale)

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 
 def _kernel(a_ref, b_ref, o_ref, h_ref, *, q: int):
@@ -71,7 +70,7 @@ def rglru_scan_kernel(a, b, *, chunk: int = 128, block_w: int = 256,
         out_specs=pl.BlockSpec((1, q, bw), lambda i, w, c: (i, c, w)),
         out_shape=jax.ShapeDtypeStruct((B, S, W), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -65,13 +65,9 @@ def _pad_up(x: int, m: int) -> int:
 
 def _on_tpu() -> bool:
     """Whether the default jax device is a TPU (compiled-vs-interpret
-    auto-selection for this kernel and kernels/alloc_scan.py)."""
-    if not HAVE_JAX:
-        return False
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:                     # pragma: no cover
-        return False
+    auto-selection for this kernel and kernels/alloc_scan.py).  A backend
+    that fails to initialize raises here rather than passing for a CPU."""
+    return HAVE_JAX and jax.devices()[0].platform == "tpu"
 
 
 def pack_tables(lt, dt, st) -> dict:

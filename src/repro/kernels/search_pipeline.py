@@ -60,9 +60,10 @@ Variants (``engine="pipeline[:variant]"``):
 * ``pallas`` -- the staged TPU composition: an enumeration kernel
   (int32) decodes indices to masks, ``alloc_scan_pallas`` replays them,
   and a cost/argmin kernel reduces each block to one winner row.  The
-  cost kernel works in float64 for exactness and therefore always runs
-  in interpret mode off-TPU (the CI configuration); the integer
-  enumeration and allocator stages compile natively on TPU.
+  cost kernel works in float64 for exactness; Mosaic has no float64, so
+  on a TPU this variant raises (use ``lax`` there) and off the TPU the
+  f64 stages run in interpret mode.  The integer enumeration and
+  allocator stages compile natively on TPU.
 
 All three variants return the bit-identical winner
 (tests/test_search_pipeline.py fuzzes them against the host merge on
@@ -96,6 +97,9 @@ OBJECTIVES = ("latency", "sram", "dram")
 # EXHAUSTIVE_LIMIT = 8M << 2**62).
 _PAD_RANK = 2.0
 _HUGE_IDX = float(2 ** 62)
+# Largest sub-space the kernels index: linear indices are int32, with
+# headroom for the padded chunk starts past S.
+_MAX_SPACE = 2 ** 30
 
 
 # --------------------------------------------------------------- index math
@@ -180,7 +184,7 @@ def argmin_lanes(infeas, primary, secondary, idx,
         raise ValueError(f"unknown argmin_lanes backend: {backend!r}")
     if not HAVE_JAX:
         raise RuntimeError(f"argmin_lanes backend {backend!r} requires jax")
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         if backend == "lax":
             w = _argmin_hier(jnp.asarray(infeas), jnp.asarray(primary),
                              jnp.asarray(secondary), jnp.asarray(idx), jnp)
@@ -195,7 +199,8 @@ def argmin_lanes(infeas, primary, secondary, idx,
         x[1, :infeas.size] = primary
         x[2, :infeas.size] = secondary
         x[3, :infeas.size] = idx
-        row = np.asarray(_build_argmin_call(lp)(x))[0]
+        call = _build_argmin_call(lp, _f64_stage_interpret("argmin"))
+        row = np.asarray(call(x))[0]
         return (float(row[0]), float(row[1]), float(row[2]), int(row[3]))
 
 
@@ -336,16 +341,19 @@ def _make_fused(tbl, C, npfx, dims, strides, S, objective):
     def fused(lo, pref, run_of, pos_of, dir_neg, alloc32,
               lt_comp, lt_row, lt_weight, lt_side, dt_rowfm,
               st_comp, st_weight, st_outf, st_outr, st_wrr):
-        j = lo + jnp.arange(C, dtype=jnp.int64)
+        # int32 linear indices (S <= _MAX_SPACE, checked by
+        # pipeline_subspace): a TPU has no native int64, and its emulated
+        # divide makes the mixed-radix decode below compile for minutes
+        j = lo + jnp.arange(C, dtype=jnp.int32)
         parts = []
         if npfx:
             parts.append(jnp.broadcast_to(
-                pref[None, :].astype(jnp.int64), (C, npfx)))
+                pref[None, :], (C, npfx)))
         for q in range(len(dims)):
             parts.append(((j // strides[q]) % dims[q])[:, None])
         cuts = jnp.concatenate(parts, axis=1)
         cut = cuts[:, run_of]
-        pos = pos_of[None, :].astype(jnp.int64)
+        pos = pos_of[None, :]
         frame = jnp.where(dir_neg[None, :], pos >= cut, pos < cut)
         io, buff, side_buff, wrf, bfm, feas = _scan_impl(frame.T, *alloc32)
         io64 = io[:, :G].astype(jnp.float64)
@@ -390,6 +398,37 @@ def _make_fused(tbl, C, npfx, dims, strides, S, objective):
     return fused
 
 
+def _shard_fused(fused, mesh):
+    """The fused chunk step over a 1-D device mesh (axis ``"d"``): device
+    ``i`` scores the chunk starting at ``los[i]`` and returns its winner
+    row.  Contiguous linear ranges per device are the disjoint
+    partitioning of ``search_pool.partition_space``, expressed on the
+    linear index; winners merge with the same deterministic tuple order,
+    so results are device-count-invariant."""
+    from jax.sharding import PartitionSpec as P
+
+    def per_device(los, *args):
+        return fused(los[0], *args)[None, :]
+
+    # check_vma=False: the body is embarrassingly parallel (no
+    # collectives), but jax's varying-axes checker cannot see through the
+    # alloc scan's carry and rejects it.
+    return jax.jit(jax.shard_map(
+        per_device, mesh=mesh, in_specs=(P("d"),) + (P(),) * 15,
+        out_specs=P("d"), check_vma=False))
+
+
+def _lax_args(tbl, prefix) -> tuple:
+    """The fused step's operands after ``lo``: the prefix cuts, then the
+    engine's tables in ``_make_fused``'s parameter order."""
+    pref = np.asarray(prefix if prefix else [0], dtype=np.int32)
+    return (pref, tbl["run_of"], tbl["pos_of"], tbl["dir_neg"],
+            tbl["alloc32"], tbl["lt_comp"], tbl["lt_row"],
+            tbl["lt_weight"], tbl["lt_side"], tbl["dt_rowfm"],
+            tbl["st_comp"], tbl["st_weight"], tbl["st_outf"],
+            tbl["st_outr"], tbl["st_wrr"])
+
+
 def _run_lax(engine, tbl, prefix, dims, strides, S, chunk, objective):
     cache = engine.__dict__.setdefault("_pipeline_calls", {})
     npfx = len(prefix)
@@ -398,47 +437,24 @@ def _run_lax(engine, tbl, prefix, dims, strides, S, chunk, objective):
     ndev = len(jax.devices())
     if calls is None:
         fused = _make_fused(tbl, chunk, npfx, dims, strides, S, objective)
-        jfused = jax.jit(fused)
         sharded = None
         if ndev > 1:
-            # Contiguous linear ranges per device -- the disjoint
-            # partitioning of search_pool.partition_space, expressed on
-            # the linear index; winners merge with the same deterministic
-            # tuple order, so results are device-count-invariant.
-            from jax.experimental.shard_map import shard_map
-            from jax.sharding import PartitionSpec as P
-            mesh = jax.make_mesh((ndev,), ("d",))
-
-            def per_device(los, *args):
-                return fused(los[0], *args)[None, :]
-
-            # check_rep=False: the body is embarrassingly parallel (no
-            # collectives), but jax's replication checker cannot see
-            # through the alloc scan's carry and rejects it.
-            sharded = jax.jit(shard_map(
-                per_device, mesh=mesh,
-                in_specs=(P("d"),) + (P(),) * 15,
-                out_specs=P("d"), check_rep=False))
-        calls = (jfused, sharded)
+            sharded = _shard_fused(fused, jax.make_mesh((ndev,), ("d",)))
+        calls = (jax.jit(fused), sharded)
         cache[key] = calls
     jfused, sharded = calls
-    pref = np.asarray(prefix if npfx else [0], dtype=np.int32)
-    args = (pref, tbl["run_of"], tbl["pos_of"], tbl["dir_neg"],
-            tbl["alloc32"], tbl["lt_comp"], tbl["lt_row"],
-            tbl["lt_weight"], tbl["lt_side"], tbl["dt_rowfm"],
-            tbl["st_comp"], tbl["st_weight"], tbl["st_outf"],
-            tbl["st_outr"], tbl["st_wrr"])
+    args = _lax_args(tbl, prefix)
     best = None
     if sharded is not None:
         step = chunk * ndev
         for base in range(0, S, step):
-            los = base + np.arange(ndev, dtype=np.int64) * chunk
+            los = base + np.arange(ndev, dtype=np.int32) * chunk
             wins = np.asarray(sharded(los, *args))
             for row in wins:
                 best = _fold(best, row)
     else:
         for lo in range(0, S, chunk):
-            best = _fold(best, np.asarray(jfused(np.int64(lo), *args)))
+            best = _fold(best, np.asarray(jfused(np.int32(lo), *args)))
     return best
 
 
@@ -467,7 +483,9 @@ if HAVE_JAX:
                 q = r - npfx
                 val = (j // strides[q]) % dims[q]
             cut = jnp.where(runof == r, val, cut)
-        fr = jnp.where(dneg, pos >= cut, pos < cut) & (runof >= 0)
+        # and/or of comparisons, not a select between bool vectors:
+        # Mosaic cannot lower the i8 -> i1 truncation a select emits
+        fr = ((dneg & (pos >= cut)) | (~dneg & (pos < cut))) & (runof >= 0)
         out_ref[...] = fr.astype(jnp.int32)
 
     @functools.lru_cache(maxsize=64)
@@ -599,35 +617,49 @@ if HAVE_JAX:
                 ol == 2, w2, jnp.where(ol == 3, w3, 0.0))))
 
     @functools.lru_cache(maxsize=16)
-    def _build_argmin_call(lp):
+    def _build_argmin_call(lp, interpret):
         return pl.pallas_call(
             _argmin_only_kernel, grid=(1,),
             in_specs=[pl.BlockSpec((SUBLANES, lp), lambda i: (0, 0))],
             out_specs=pl.BlockSpec((1, LANES), lambda i: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((1, LANES), jnp.float64),
-            interpret=True)
+            interpret=interpret)
+
+
+def _f64_stage_interpret(stage: str) -> bool:
+    """Interpret flag for the float64 Pallas stages (cost, argmin).
+
+    Mosaic has no float64, so these stages cannot compile for a TPU; on
+    a TPU host they refuse instead of silently running the Pallas
+    interpreter on the chip.  Off the TPU they run interpreted, which is
+    how the tests exercise them."""
+    if _on_tpu():
+        raise NotImplementedError(
+            f"pipeline:pallas {stage} stage computes in float64, which "
+            f"Mosaic cannot compile for a TPU; use engine='pipeline:lax' "
+            f"on TPU hosts")
+    return True
 
 
 def _run_pallas(engine, tbl, prefix, dims, strides, S, chunk, objective):
     """Staged Pallas composition: enumeration kernel (i32, compiled on
     TPU) -> ``alloc_scan_pallas`` (i32) -> f64 cost/argmin kernel.  The
-    cost stage is float64 for oracle exactness and so always runs in
-    interpret mode off-TPU (and on TPU, where the hardware has no f64
-    lanes); the masks passed between stages are B x G bitmaps, never
-    candidate tuples."""
+    cost stage is float64 for oracle exactness, so this variant runs off
+    the TPU only (``_f64_stage_interpret``); the masks passed between
+    stages are B x G bitmaps, never candidate tuples."""
     from repro.kernels.alloc_scan import alloc_scan_pallas
     G, lanes = tbl["n"], tbl["lanes"]
     nr = len(prefix) + len(dims)
     block_b = max(SUBLANES, min(256, _pad_up(max(chunk, 1), SUBLANES)))
     bp = _pad_up(max(chunk, 1), block_b)
     nb = bp // block_b
-    enum_interpret = not _on_tpu()
-    enum_call = _build_enum_call(nb, block_b, lanes, nr, len(prefix),
-                                 strides, dims, enum_interpret)
     cost_call = _build_cost_call(nb, block_b, lanes, tbl["bpc"],
                                  tbl["goc"], float(tbl["budget"]),
                                  float(tbl["weight_bytes"]),
-                                 float(tbl["row_buff"]), objective, True)
+                                 float(tbl["row_buff"]), objective,
+                                 _f64_stage_interpret("cost"))
+    enum_call = _build_enum_call(nb, block_b, lanes, nr, len(prefix),
+                                 strides, dims, not _on_tpu())
     pref = np.asarray(list(prefix) if prefix else [0], dtype=np.int32)
     best = None
     for lo in range(0, S, chunk):
@@ -645,7 +677,7 @@ def _run_pallas(engine, tbl, prefix, dims, strides, S, chunk, objective):
         stats[:c, 4] = res.wrf
         stats[:c, 5] = res.bfm
         stats[:c, 6] = res.feasible
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             rows = np.asarray(cost_call(
                 np.asarray([lo, S], dtype=np.int32), frame_pad, io_pad,
                 stats, tbl["tab"]))
@@ -685,6 +717,9 @@ def pipeline_subspace(engine, prefix, suffix_dims, objective: str,
     S = 1
     for d in dims:
         S *= d
+    if S > _MAX_SPACE:
+        raise ValueError(f"sub-space of {S} candidates exceeds the "
+                         f"pipeline's int32 index range ({_MAX_SPACE})")
     before = engine.evaluations
 
     def finish(cuts):
@@ -704,7 +739,7 @@ def pipeline_subspace(engine, prefix, suffix_dims, objective: str,
         best = _run_reference(engine, tbl, prefix, dims, strides, S,
                               chunk, objective)
     elif variant == "lax":
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             best = _run_lax(engine, tbl, prefix, dims, strides, S,
                             chunk, objective)
     else:

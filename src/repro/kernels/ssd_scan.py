@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 
 def _kernel(x_ref, dt_ref, a_ref, d_ref, b_ref, c_ref, o_ref,
@@ -94,7 +93,7 @@ def ssd_scan(x, dt, A, D, Bm, Cm, *, chunk: int = 256, nheads: int,
         out_specs=pl.BlockSpec((1, q, P), lambda h, c: (h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, A, D, Bm, Cm)

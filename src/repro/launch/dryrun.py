@@ -148,8 +148,6 @@ def run_cell(arch: str, shape: str, multi_pod: bool = False,
 
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):      # older jax: one dict per program
-            cost = cost[0] if cost else {}
         coll = parse_collectives(compiled.as_text())
 
     flops = float((cost or {}).get("flops", 0.0))

@@ -141,10 +141,11 @@ def test_scan_backend_matches_reference(name):
     _assert_scan_equal(alloc_scan_jax(tables, frame), journal, name)
 
 
-@pytest.mark.parametrize("name", ["vgg16-conv", "resnet50"])
+@pytest.mark.parametrize("name", ["vgg16-conv", "resnet50", "resnet152"])
 def test_pallas_backend_matches_reference(name):
     """Pallas interpret-mode replay == numpy reference (integer-exact,
-    unlike the float32 scoring kernel)."""
+    unlike the float32 scoring kernel); resnet152's 160 groups span two
+    128-lane tiles."""
     pytest.importorskip("jax")
     from repro.kernels.alloc_scan import alloc_scan_pallas
     gg, blocks, runs = _grouped(name)

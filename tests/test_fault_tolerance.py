@@ -258,6 +258,25 @@ def test_device_replay_falls_back_to_journal_loudly(resnet):
     assert "journal engine substituted" in falls[0].detail
 
 
+@needs_fork
+def test_device_replay_deterministic_error_propagates(resnet, monkeypatch):
+    """A deterministic device failure (a kernel that does not compile, a
+    shape error) must surface, not hide behind the journal engine: the
+    search raises it unchanged, with no fallback and no retry."""
+    gg, _ = resnet
+
+    def broken(self, frame, skip=None):
+        raise ValueError("Incompatible shapes for broadcasting")
+
+    # fork-started workers inherit the patched engine method
+    monkeypatch.setattr(search_pool._cp.CutpointEngine, "_device_replay",
+                        broken)
+    with ParallelSearchDriver(workers=2, mp_context="fork",
+                              max_retries=5) as d:
+        with pytest.raises(ValueError, match="Incompatible shapes"):
+            d.search(gg, KCU1500, TEST_OPTS.replace(engine="device"))
+
+
 def test_chaos_hold_gate_mechanics():
     """hold events need a gate, release unblocks fire(), and attempts at
     or past max_attempt (the straggler duplicate) never block."""

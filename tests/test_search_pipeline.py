@@ -105,6 +105,21 @@ def test_argmin_lanes_duplicated_key_takes_smallest_index():
                             backend=backend) == (0.0, 5.0, 2.0, 4), backend
 
 
+def test_pallas_variant_refuses_f64_stages_on_tpu(monkeypatch):
+    """Mosaic has no float64: on a TPU the pallas variant's f64 cost and
+    argmin stages raise instead of running the Pallas interpreter on the
+    chip."""
+    import repro.kernels.search_pipeline as sp
+    monkeypatch.setattr(sp, "_on_tpu", lambda: True)
+    engine, runs = _engine("vgg16-conv")
+    with pytest.raises(NotImplementedError, match="cost stage"):
+        pipeline_subspace(engine, (), [len(r) for r in runs], "latency",
+                          variant="pallas")
+    with pytest.raises(NotImplementedError, match="argmin stage"):
+        argmin_lanes(np.zeros(3), np.zeros(3), np.zeros(3), np.arange(3.0),
+                     backend="pallas")
+
+
 def test_argmin_lanes_rejects_bad_input():
     with pytest.raises(ValueError, match="equal-length"):
         argmin_lanes(np.zeros(3), np.zeros(2), np.zeros(3), np.zeros(3))
@@ -280,56 +295,31 @@ print("SHARDED-OK", piped.evaluated)
     assert "SHARDED-OK" in out.stdout, out.stdout
 
 
-@pytest.mark.skipif("fork" not in
-                    __import__("multiprocessing").get_all_start_methods(),
-                    reason="no fork start method on this platform")
-def test_parallel_pipeline_ratchets_fork_to_spawn():
-    # Forking a parent that has already run jit'd code hands the children
-    # XLA's locked mutexes and deadlocks them, so the driver must ratchet
-    # its *defaulted* fork context to spawn exactly for the engine specs
-    # whose workers execute jax -- and leave explicit contexts alone.
+@needs_jax
+def test_parallel_pipeline_runs_in_process():
+    """A chip belongs to one process, so a jax engine never enters the
+    worker pool: a workers=2 search on pipeline:lax -- whole-space and
+    over an explicit partition -- runs in the calling process and is
+    journal-identical."""
     from repro.core.options import resolve_engine
     from repro.core.search_pool import (ParallelSearchDriver,
                                         _engine_needs_jax)
 
-    assert _engine_needs_jax(resolve_engine("pipeline:lax"))
-    assert _engine_needs_jax(resolve_engine("pipeline:pallas"))
-    assert _engine_needs_jax(resolve_engine("device:scan"))
-    assert _engine_needs_jax(resolve_engine("device:pallas"))
-    assert not _engine_needs_jax(resolve_engine("journal"))
-    assert not _engine_needs_jax(resolve_engine("pipeline:reference"))
-    assert not _engine_needs_jax(resolve_engine("device"))  # -> reference
+    for spelling in ("pipeline:lax", "pipeline:pallas", "device:scan",
+                     "device:pallas"):
+        assert _engine_needs_jax(resolve_engine(spelling)), spelling
+    for spelling in ("journal", "pipeline:reference", "device"):
+        assert not _engine_needs_jax(resolve_engine(spelling)), spelling
 
-    opts = CompileOptions(exhaustive_limit=TEST_LIMIT)
+    gg = group_nodes(build_cnn("resnet50"))
+    journal = search(gg, KCU1500, TEST_OPTS)
+    opts = TEST_OPTS.replace(engine="pipeline:lax", workers=2)
+    runs = monotone_runs(split_blocks(gg))
+    prefixes, suffix_dims = partition_space(runs, target_tasks=16)
     with ParallelSearchDriver(workers=2) as d:
-        out = d._jax_safe_opts(opts)
-        assert out.engine == "journal" and \
-            d._ctx.get_start_method() == "fork"
-        out = d._jax_safe_opts(opts.replace(engine="pipeline:lax"))
-        assert out.engine == "pipeline:lax"
-        assert d._ctx.get_start_method() == "spawn"
-        # one-way for the driver's lifetime: later numpy engines reuse
-        # the (universally safe) spawn pool instead of churning workers
-        d._jax_safe_opts(opts)
-        assert d._ctx.get_start_method() == "spawn"
-
-    # an explicit context is the caller's choice, hazards included
-    with ParallelSearchDriver(workers=2, mp_context="fork") as d:
-        out = d._jax_safe_opts(opts.replace(engine="pipeline:lax"))
-        assert out.engine == "pipeline:lax"
-        assert d._ctx.get_start_method() == "fork"
-
-    # a parent whose __main__ spawn cannot re-import (stdin scripts)
-    # degrades the engine to the bit-identical journal replay instead
-    from repro.core import search_pool as sp
-    with ParallelSearchDriver(workers=2) as d:
-        orig = sp._spawn_main_viable
-        sp._spawn_main_viable = lambda: False
-        try:
-            with pytest.warns(RuntimeWarning, match="journal engine"):
-                out = d._jax_safe_opts(
-                    opts.replace(engine="pipeline:lax@512"))
-        finally:
-            sp._spawn_main_viable = orig
-        assert out.engine == "journal@512"
-        assert d._ctx.get_start_method() == "fork"
+        whole = d.search(gg, KCU1500, opts)
+        parts = d.run_subspaces(gg, KCU1500, prefixes, suffix_dims, opts)
+        assert d._pool is None             # no worker process was started
+    assert_results_identical(journal, whole, ctx="pipeline-in-process")
+    assert_results_identical(journal, parts, ctx="pipeline-partitioned")
+    assert not whole.events and not parts.events
