@@ -65,13 +65,13 @@ def log(msg: str) -> None:
 
 
 def require_tpu(chips: int):
-    """The device check: a TPU with at least ``chips`` devices, or exit."""
+    """The device check: exactly ``chips`` TPU devices, or exit."""
     import jax
     devs = jax.devices()
     if devs[0].platform != "tpu":
         sys.exit(f"chip_smoke: jax found no TPU (platform "
                  f"{devs[0].platform!r}); refusing to run on the CPU")
-    if chips > 1 and len(devs) != chips:
+    if len(devs) != chips:
         sys.exit(f"chip_smoke: --chips {chips} but jax sees {len(devs)} "
                  f"devices")
     return devs

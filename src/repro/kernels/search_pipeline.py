@@ -97,6 +97,9 @@ OBJECTIVES = ("latency", "sram", "dram")
 # EXHAUSTIVE_LIMIT = 8M << 2**62).
 _PAD_RANK = 2.0
 _HUGE_IDX = float(2 ** 62)
+# The float64 cost and argmin kernels always run in the Pallas
+# interpreter: Mosaic has no float64 (see _refuse_f64_on_tpu).
+_F64_INTERPRET = True
 # Largest sub-space the kernels index: linear indices are int32, with
 # headroom for the padded chunk starts past S.
 _MAX_SPACE = 2 ** 30
@@ -199,8 +202,8 @@ def argmin_lanes(infeas, primary, secondary, idx,
         x[1, :infeas.size] = primary
         x[2, :infeas.size] = secondary
         x[3, :infeas.size] = idx
-        call = _build_argmin_call(lp, _f64_stage_interpret("argmin"))
-        row = np.asarray(call(x))[0]
+        _refuse_f64_on_tpu("argmin")
+        row = np.asarray(_build_argmin_call(lp)(x))[0]
         return (float(row[0]), float(row[1]), float(row[2]), int(row[3]))
 
 
@@ -587,7 +590,7 @@ if HAVE_JAX:
 
     @functools.lru_cache(maxsize=64)
     def _build_cost_call(nb, block_b, lanes, bpc, goc, budget, wbytes,
-                         row_buff, obj, interpret):
+                         row_buff, obj):
         kernel = functools.partial(_cost_kernel, block_b=block_b,
                                    lanes=lanes, bpc=bpc, goc=goc,
                                    budget=budget, wbytes=wbytes,
@@ -605,7 +608,7 @@ if HAVE_JAX:
         return pl.pallas_call(
             kernel, grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((nb, LANES), jnp.float64),
-            interpret=interpret)
+            interpret=_F64_INTERPRET)
 
     def _argmin_only_kernel(in_ref, out_ref):
         x = in_ref[...]
@@ -617,35 +620,33 @@ if HAVE_JAX:
                 ol == 2, w2, jnp.where(ol == 3, w3, 0.0))))
 
     @functools.lru_cache(maxsize=16)
-    def _build_argmin_call(lp, interpret):
+    def _build_argmin_call(lp):
         return pl.pallas_call(
             _argmin_only_kernel, grid=(1,),
             in_specs=[pl.BlockSpec((SUBLANES, lp), lambda i: (0, 0))],
             out_specs=pl.BlockSpec((1, LANES), lambda i: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((1, LANES), jnp.float64),
-            interpret=interpret)
+            interpret=_F64_INTERPRET)
 
 
-def _f64_stage_interpret(stage: str) -> bool:
-    """Interpret flag for the float64 Pallas stages (cost, argmin).
+def _refuse_f64_on_tpu(stage: str) -> None:
+    """Raise on a TPU before a float64 Pallas stage (cost, argmin) runs.
 
-    Mosaic has no float64, so these stages cannot compile for a TPU; on
-    a TPU host they refuse instead of silently running the Pallas
-    interpreter on the chip.  Off the TPU they run interpreted, which is
-    how the tests exercise them."""
+    Mosaic has no float64, so these stages exist only in the Pallas
+    interpreter (``_F64_INTERPRET``); on a TPU host they refuse instead of
+    silently interpreting on the chip.  Off the TPU the tests run them."""
     if _on_tpu():
         raise NotImplementedError(
             f"pipeline:pallas {stage} stage computes in float64, which "
             f"Mosaic cannot compile for a TPU; use engine='pipeline:lax' "
             f"on TPU hosts")
-    return True
 
 
 def _run_pallas(engine, tbl, prefix, dims, strides, S, chunk, objective):
     """Staged Pallas composition: enumeration kernel (i32, compiled on
     TPU) -> ``alloc_scan_pallas`` (i32) -> f64 cost/argmin kernel.  The
     cost stage is float64 for oracle exactness, so this variant runs off
-    the TPU only (``_f64_stage_interpret``); the masks passed between
+    the TPU only (``_refuse_f64_on_tpu``); the masks passed between
     stages are B x G bitmaps, never candidate tuples."""
     from repro.kernels.alloc_scan import alloc_scan_pallas
     G, lanes = tbl["n"], tbl["lanes"]
@@ -653,11 +654,11 @@ def _run_pallas(engine, tbl, prefix, dims, strides, S, chunk, objective):
     block_b = max(SUBLANES, min(256, _pad_up(max(chunk, 1), SUBLANES)))
     bp = _pad_up(max(chunk, 1), block_b)
     nb = bp // block_b
+    _refuse_f64_on_tpu("cost")
     cost_call = _build_cost_call(nb, block_b, lanes, tbl["bpc"],
                                  tbl["goc"], float(tbl["budget"]),
                                  float(tbl["weight_bytes"]),
-                                 float(tbl["row_buff"]), objective,
-                                 _f64_stage_interpret("cost"))
+                                 float(tbl["row_buff"]), objective)
     enum_call = _build_enum_call(nb, block_b, lanes, nr, len(prefix),
                                  strides, dims, not _on_tpu())
     pref = np.asarray(list(prefix) if prefix else [0], dtype=np.int32)

@@ -16,7 +16,7 @@ REPO_ROOT = Path(__file__).resolve().parents[3]
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
-def enable_compile_cache(root: Path = REPO_ROOT) -> str:
+def enable_compile_cache() -> str:
     """Turn on jax's persistent compilation cache; returns its directory.
 
     Every program is cached, however fast it compiled: the Pallas kernels
@@ -26,7 +26,7 @@ def enable_compile_cache(root: Path = REPO_ROOT) -> str:
 
     path = os.environ.get(CACHE_ENV)
     if not path:
-        path = str(Path(root) / ".jax_cache")
+        path = str(REPO_ROOT / ".jax_cache")
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return path

@@ -54,6 +54,20 @@ def test_compare_rejects_any_difference(smoke, got, match):
         smoke.compare("net", got, _result())
 
 
+@pytest.mark.parametrize("chips,visible,ok", [
+    (1, 1, True), (1, 4, False), (4, 4, True), (4, 1, False)])
+def test_require_tpu_wants_exactly_the_chips_asked(smoke, monkeypatch,
+                                                   chips, visible, ok):
+    import jax
+    tpu = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    monkeypatch.setattr(jax, "devices", lambda: [tpu] * visible)
+    if ok:
+        assert len(smoke.require_tpu(chips)) == chips
+    else:
+        with pytest.raises(SystemExit, match=f"jax sees {visible} devices"):
+            smoke.require_tpu(chips)
+
+
 def _run(args, cwd, env):
     return subprocess.run([sys.executable, *args], cwd=cwd, env=env,
                           capture_output=True, text=True, timeout=300)
