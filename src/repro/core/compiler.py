@@ -39,6 +39,7 @@ from repro.core.ir import Graph
 from repro.core.isa import GroupInstruction, generate_instructions
 from repro.core.sram import SRAMReport, sram_report
 from repro.core.timing import LatencyReport, latency_report
+from repro.utils.trace import span
 
 
 @dataclass
@@ -145,34 +146,41 @@ def compile_graph(graph: Graph, hw: FPGAConfig = KCU1500,
     oracle and seeds the branch-and-bound incumbent -- exhaustive-path
     results stay bit-identical to a cold compile.
     """
-    opts = resolve_options(options, legacy, site="compile_graph")
-    graph.validate()
-    gg = group_nodes(graph)
-    result: SearchResult | None = None
-    if policy is None:
-        result = search(gg, hw, opts, guard=guard, warm_start=warm_start)
-        cand = result.best
-        alloc = cand.alloc
-    else:
-        alloc = allocate(gg, policy)
-    sram = sram_report(gg, alloc, hw)
-    dram = dram_report(gg, alloc)
-    latency = latency_report(gg, alloc, hw)
-    if policy is not None:
-        feasible = (sram.sram_total <= hw.sram_budget
-                    and frame_feasible(gg, policy, alloc))
-        cand = Candidate(
-            cuts=(), policy=policy, alloc=alloc,
-            latency_cycles=latency.cycles,
-            dram_total=dram.total, dram_fm=dram.fm_bytes,
-            sram_total=sram.sram_total, bram18k=sram.bram18k,
-            feasible=feasible)
-    plan = ExecutionPlan(
-        graph=graph, grouped=gg, hw=hw, candidate=cand, alloc=alloc,
-        sram=sram, dram=dram, latency=latency,
-        instructions=generate_instructions(gg, alloc),
-        search=result)
-    return apply_verification(plan, opts.verify)
+    with span("compile"):
+        opts = resolve_options(options, legacy, site="compile_graph")
+        graph.validate()
+        with span("compile.group"):
+            gg = group_nodes(graph)
+        result: SearchResult | None = None
+        if policy is None:
+            with span("compile.search"):
+                result = search(gg, hw, opts, guard=guard,
+                                warm_start=warm_start)
+            cand = result.best
+            alloc = cand.alloc
+        else:
+            alloc = allocate(gg, policy)
+        with span("compile.materialise"):
+            sram = sram_report(gg, alloc, hw)
+            dram = dram_report(gg, alloc)
+            latency = latency_report(gg, alloc, hw)
+        if policy is not None:
+            feasible = (sram.sram_total <= hw.sram_budget
+                        and frame_feasible(gg, policy, alloc))
+            cand = Candidate(
+                cuts=(), policy=policy, alloc=alloc,
+                latency_cycles=latency.cycles,
+                dram_total=dram.total, dram_fm=dram.fm_bytes,
+                sram_total=sram.sram_total, bram18k=sram.bram18k,
+                feasible=feasible)
+        with span("compile.codegen"):
+            instructions = generate_instructions(gg, alloc)
+        plan = ExecutionPlan(
+            graph=graph, grouped=gg, hw=hw, candidate=cand, alloc=alloc,
+            sram=sram, dram=dram, latency=latency,
+            instructions=instructions, search=result)
+        with span("compile.verify"):
+            return apply_verification(plan, opts.verify)
 
 
 def all_row_policy(gg: GroupedGraph) -> dict[int, str]:

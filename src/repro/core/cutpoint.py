@@ -115,6 +115,7 @@ from repro.core.sram import (sram_report, sram_tables, sram_total_fast,
                              sram_total_fast_batch)
 from repro.core.timing import (latency_cycles_fast, latency_cycles_fast_batch,
                                latency_report, latency_tables)
+from repro.utils.trace import span
 
 
 # ------------------------------------------------------------------- blocks
@@ -1264,8 +1265,9 @@ PreemptionGuard` the pool polls for clean SIGTERM drain) and
     for r in runs:
         space *= len(r) + 1
 
-    engine = CutpointEngine(gg, hw, blocks, runs, backend=opts.backend,
-                            engine=opts.engine)
+    with span("search.engine"):
+        engine = CutpointEngine(gg, hw, blocks, runs, backend=opts.backend,
+                                engine=opts.engine)
     spec = opts.engine_spec()
     objective, batch_size = opts.objective, spec.batch_size
 

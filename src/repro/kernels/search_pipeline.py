@@ -81,6 +81,7 @@ from repro.core.sram import sram_total_fast_batch
 from repro.core.timing import latency_cycles_fast_batch
 from repro.kernels.score_batch import (HAVE_JAX, LANES, SUBLANES, _on_tpu,
                                        _pad_up)
+from repro.utils.trace import span
 
 if HAVE_JAX:
     import jax
@@ -219,10 +220,18 @@ def _fold(best, w):
 # ------------------------------------------------------------- shared tables
 def _engine_tables(engine) -> dict:
     """Per-engine prepared arrays for the fused variants (built once and
-    stashed on the engine, like its ``_at`` alloc tables)."""
+    stashed on the engine, with its ``_at`` alloc tables)."""
     tbl = engine.__dict__.get("_pipeline_tables")
     if tbl is not None:
         return tbl
+    with span("pipeline.tables"):
+        return _build_engine_tables(engine)
+
+
+def _build_engine_tables(engine) -> dict:
+    if engine._at is None:
+        from repro.kernels.alloc_scan import pack_alloc_tables
+        engine._at = pack_alloc_tables(engine.gg, engine.hw)
     at = engine._at
     lt, dt, st = engine._lt, engine._dt, engine._st
     hw = engine.hw
@@ -445,6 +454,11 @@ def _run_lax(engine, tbl, prefix, dims, strides, S, chunk, objective):
             sharded = _shard_fused(fused, jax.make_mesh((ndev,), ("d",)))
         calls = (jax.jit(fused), sharded)
         cache[key] = calls
+        # the first call of a newly built jitted step traces, lowers and
+        # loads it
+        name = "pipeline.load"
+    else:
+        name = "pipeline.dispatch"
     jfused, sharded = calls
     args = _lax_args(tbl, prefix)
     best = None
@@ -452,12 +466,22 @@ def _run_lax(engine, tbl, prefix, dims, strides, S, chunk, objective):
         step = chunk * ndev
         for base in range(0, S, step):
             los = base + np.arange(ndev, dtype=np.int32) * chunk
-            wins = np.asarray(sharded(los, *args))
-            for row in wins:
+            with span(name):
+                out = sharded(los, *args)
+            name = "pipeline.dispatch"
+            with span("pipeline.wait"):
+                # rebinding frees the device result before the next launch
+                out = np.asarray(out)
+            for row in out:
                 best = _fold(best, row)
     else:
         for lo in range(0, S, chunk):
-            best = _fold(best, np.asarray(jfused(np.int32(lo), *args)))
+            with span(name):
+                out = jfused(np.int32(lo), *args)
+            name = "pipeline.dispatch"
+            with span("pipeline.wait"):
+                out = np.asarray(out)
+            best = _fold(best, out)
     return best
 
 
@@ -721,34 +745,34 @@ def pipeline_subspace(engine, prefix, suffix_dims, objective: str,
     if S > _MAX_SPACE:
         raise ValueError(f"sub-space of {S} candidates exceeds the "
                          f"pipeline's int32 index range ({_MAX_SPACE})")
-    before = engine.evaluations
-
-    def finish(cuts):
-        [m] = engine.score_batch([cuts], memoize=False)
-        engine.evaluations = before + S
-        return m, 0
-
-    if S == 1:
-        return finish(prefix + (0,) * len(dims))
-    if engine._at is None:
-        from repro.kernels.alloc_scan import pack_alloc_tables
-        engine._at = pack_alloc_tables(engine.gg, engine.hw)
-    tbl = _engine_tables(engine)
-    strides = _space_strides(dims)
     chunk = max(1, int(batch_size))
-    if variant == "reference":
-        best = _run_reference(engine, tbl, prefix, dims, strides, S,
-                              chunk, objective)
-    elif variant == "lax":
-        with jax.enable_x64(True):
-            best = _run_lax(engine, tbl, prefix, dims, strides, S,
-                            chunk, objective)
-    else:
-        # manages its own x64 scope: the i32 enumeration/allocator
-        # stages must trace *without* x64 (weak int literals would
-        # promote), only the f64 cost stage runs under it
-        best = _run_pallas(engine, tbl, prefix, dims, strides, S,
-                           chunk, objective)
-    assert best is not None and best[0] < _PAD_RANK
-    win = int(best[3])
-    return finish(prefix + _decode_index(win, strides, dims))
+    # the host fold of launch winners is this span's own time
+    with span("pipeline.subspace"):
+        before = engine.evaluations
+
+        def finish(cuts):
+            with span("pipeline.rescore"):
+                [m] = engine.score_batch([cuts], memoize=False)
+            engine.evaluations = before + S
+            return m, 0
+
+        if S == 1:
+            return finish(prefix + (0,) * len(dims))
+        tbl = _engine_tables(engine)
+        strides = _space_strides(dims)
+        if variant == "reference":
+            best = _run_reference(engine, tbl, prefix, dims, strides, S,
+                                  chunk, objective)
+        elif variant == "lax":
+            with jax.enable_x64(True):
+                best = _run_lax(engine, tbl, prefix, dims, strides, S,
+                                chunk, objective)
+        else:
+            # manages its own x64 scope: the i32 enumeration/allocator
+            # stages must trace *without* x64 (weak int literals would
+            # promote), only the f64 cost stage runs under it
+            best = _run_pallas(engine, tbl, prefix, dims, strides, S,
+                               chunk, objective)
+        assert best is not None and best[0] < _PAD_RANK
+        win = int(best[3])
+        return finish(prefix + _decode_index(win, strides, dims))
