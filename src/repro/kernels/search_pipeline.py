@@ -51,12 +51,14 @@ Variants (``engine="pipeline[:variant]"``):
 * ``lax`` -- one jitted fused function per sub-space shape: decode,
   frame masks, ``_scan_impl`` allocator scan, f64 reductions and the
   hierarchical argmin all in a single XLA computation returning four
-  scalars.  With more than one visible device the chunk range is
-  sharded with ``shard_map`` over contiguous index ranges -- the same
-  disjoint partitioning ``search_pool.partition_space`` uses, expressed
-  on the linear index -- and the per-device winners are folded with the
-  same deterministic tuple comparison, so the merged result is
-  bit-identical at any device count.
+  scalars.  The engine's tables go to the device once, on its first
+  launch, so a launch transfers only its start index.  With more than
+  one visible device the chunk range is sharded with ``shard_map`` over
+  contiguous index ranges -- the same disjoint partitioning
+  ``search_pool.partition_space`` uses, expressed on the linear index --
+  and the per-device winners are folded with the same deterministic
+  tuple comparison, so the merged result is bit-identical at any device
+  count.
 * ``pallas`` -- the staged TPU composition: an enumeration kernel
   (int32) decodes indices to masks, ``alloc_scan_pallas`` replays them,
   and a cost/argmin kernel reduces each block to one winner row.  The
@@ -441,17 +443,40 @@ def _lax_args(tbl, prefix) -> tuple:
             tbl["st_outr"], tbl["st_wrr"])
 
 
+def _device_tables(engine, tables, ndev):
+    """``(mesh, placement, device copy of tables)`` for ``ndev`` devices,
+    put on the device on the engine's first launch there and kept for its
+    life: a launch then uploads only its start index.  One device leaves
+    the copies uncommitted, as host operands are, so the jitted step's
+    key and lowering do not change; several get them replicated over the
+    mesh the sharded step runs on (mesh and placement None on one).
+    Called inside the caller's x64 scope, so the f64 cost tables stay
+    f64."""
+    held = engine.__dict__.setdefault("_pipeline_operands", {})
+    got = held.get(ndev)
+    if got is None:
+        with span("pipeline.upload"):
+            mesh = placement = None
+            if ndev > 1:
+                from jax.sharding import NamedSharding, PartitionSpec as P
+                mesh = jax.make_mesh((ndev,), ("d",))
+                placement = NamedSharding(mesh, P())
+            got = (mesh, placement, jax.device_put(tables, placement))
+        held[ndev] = got
+    return got
+
+
 def _run_lax(engine, tbl, prefix, dims, strides, S, chunk, objective):
     cache = engine.__dict__.setdefault("_pipeline_calls", {})
     npfx = len(prefix)
     key = ("lax", chunk, npfx, dims, objective)
-    calls = cache.get(key)
     ndev = len(jax.devices())
+    pref, *tables = _lax_args(tbl, prefix)
+    mesh, placement, tables = _device_tables(engine, tuple(tables), ndev)
+    calls = cache.get(key)
     if calls is None:
         fused = _make_fused(tbl, chunk, npfx, dims, strides, S, objective)
-        sharded = None
-        if ndev > 1:
-            sharded = _shard_fused(fused, jax.make_mesh((ndev,), ("d",)))
+        sharded = None if mesh is None else _shard_fused(fused, mesh)
         calls = (jax.jit(fused), sharded)
         cache[key] = calls
         # the first call of a newly built jitted step traces, lowers and
@@ -460,7 +485,7 @@ def _run_lax(engine, tbl, prefix, dims, strides, S, chunk, objective):
     else:
         name = "pipeline.dispatch"
     jfused, sharded = calls
-    args = _lax_args(tbl, prefix)
+    args = (jax.device_put(pref, placement),) + tables
     best = None
     if sharded is not None:
         step = chunk * ndev

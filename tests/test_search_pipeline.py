@@ -156,20 +156,111 @@ def test_pipeline_subspace_matches_branch_bound(variant):
 
 
 @pytest.mark.parametrize("objective", OBJECTIVES)
-def test_pipeline_subspace_objectives(objective):
+def test_pipeline_subspace_objectives(objective, monkeypatch):
+    """Every variant returns the journal's plan, from the same winner
+    row as the numpy reference (the lax variant's from its device-held
+    tables, two sub-spaces on one engine)."""
+    import repro.kernels.search_pipeline as sp
     engine, runs = _engine()
     prefixes, suffix_dims = partition_space(runs, target_tasks=8)
     host = CutpointEngine(engine.gg, engine.hw, engine.blocks, engine.runs)
     variants = _jax_variants()
-    want, _ = branch_bound_subspace(host, prefixes[0], suffix_dims,
-                                    objective, prune=False)
+    rows = {}
     for variant in variants:
-        got, _ = pipeline_subspace(engine, prefixes[0], suffix_dims,
-                                   objective, batch_size=128,
-                                   variant=variant)
-        assert got.cuts == want.cuts, (objective, variant)
-        for f in METRICS:
-            assert getattr(got, f) == getattr(want, f), (objective, variant)
+        run = getattr(sp, f"_run_{variant}")
+
+        def recorded(*args, run=run, variant=variant):
+            best = run(*args)
+            rows.setdefault(variant, []).append(best)
+            return best
+        monkeypatch.setattr(sp, f"_run_{variant}", recorded)
+    for prefix in prefixes[:2]:
+        want, _ = branch_bound_subspace(host, prefix, suffix_dims,
+                                        objective, prune=False)
+        for variant in variants:
+            got, _ = pipeline_subspace(engine, prefix, suffix_dims,
+                                       objective, batch_size=128,
+                                       variant=variant)
+            assert got.cuts == want.cuts, (objective, variant)
+            for f in METRICS:
+                assert getattr(got, f) == getattr(want, f), (objective,
+                                                             variant)
+    for variant in variants:
+        assert rows[variant] == rows["reference"], (objective, variant)
+
+
+def _device_operands(engine):
+    """The lax step for ``engine``'s whole space on one device, with its
+    host operands and the device copies a launch passes instead."""
+    import jax
+    import repro.kernels.search_pipeline as sp
+    tbl = sp._engine_tables(engine)
+    dims = tuple(len(r) + 1 for r in engine.runs)
+    fused = sp._make_fused(tbl, 128, 0, dims, sp._space_strides(dims),
+                           int(np.prod(dims)), "latency")
+    host = sp._lax_args(tbl, ())
+    _mesh, placement, tables = sp._device_tables(engine, host[1:], 1)
+    assert placement is None
+    return fused, host, (jax.device_put(host[0]),) + tables
+
+
+@needs_jax
+def test_device_operands_lower_like_host_operands():
+    """One device: the operands stay uncommitted, so the jitted step
+    lowers to the same program as with host arrays."""
+    import jax
+    engine, _ = _engine()
+    with jax.enable_x64(True):
+        fused, host, dev = _device_operands(engine)
+        for a in jax.tree.leaves(dev):
+            assert isinstance(a, jax.Array) and not a.committed
+        lo = np.int32(0)
+        assert (jax.jit(fused).lower(lo, *dev).as_text()
+                == jax.jit(fused).lower(lo, *host).as_text())
+
+
+@needs_jax
+def test_device_operands_keep_host_dtypes_and_values():
+    """Put on the device inside the x64 scope: f64 cost tables stay
+    f64, and every copy holds its host table's values."""
+    import jax
+    engine, _ = _engine()
+    with jax.enable_x64(True):
+        _, host, dev = _device_operands(engine)
+    pairs = list(zip(jax.tree.leaves(host), jax.tree.leaves(dev)))
+    assert len(pairs) == 26
+    assert any(h.dtype == np.float64 for h, _ in pairs)
+    for h, d in pairs:
+        assert d.dtype == h.dtype and d.shape == h.shape
+        assert np.array_equal(np.asarray(d), h)
+
+
+@needs_jax
+def test_tables_upload_once_per_engine(tmp_path):
+    """Under the profiler, an engine's first sub-space opens the one
+    ``pipeline.upload`` span; later sub-spaces reuse the device copies,
+    and a new engine uploads its own."""
+    import jax
+    from repro.utils import trace
+    engine, runs = _engine()
+    prefixes, suffix_dims = partition_space(runs, target_tasks=8)
+    trace.clear()
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            for prefix in prefixes[:5]:
+                pipeline_subspace(engine, prefix, suffix_dims, "latency",
+                                  batch_size=512, variant="lax")
+            names = [r.name for r in trace.records()]
+            assert names.count("pipeline.upload") == 1
+            assert names.count("pipeline.subspace") == 5
+            fresh, _ = _engine()
+            pipeline_subspace(fresh, prefixes[0], suffix_dims, "latency",
+                              batch_size=512, variant="lax")
+            names = [r.name for r in trace.records()]
+    finally:
+        trace.clear()
+    assert names.count("pipeline.upload") == 2
+    assert list(engine._pipeline_operands) == [1]
 
 
 def test_pipeline_subspace_counts_full_enumeration():
@@ -260,8 +351,9 @@ def test_search_pipeline_batch_suffix():
 def test_search_pipeline_sharded_two_devices():
     """The shard_map path: a subprocess forced to expose two host
     devices must produce the identical SearchResult as the journal
-    engine (contiguous index ranges per device, deterministic merge).
-    Subprocess because device count is fixed at first jax import."""
+    engine (contiguous index ranges per device, deterministic merge),
+    with the tables replicated on both devices.  Subprocess because
+    device count is fixed at first jax import."""
     code = """
 import jax
 assert jax.device_count() == 2, jax.devices()
@@ -279,6 +371,20 @@ for f in ("latency_cycles", "dram_total", "dram_fm", "sram_total",
           "bram18k", "feasible"):
     assert getattr(piped.best, f) == getattr(journal.best, f), f
 assert piped.evaluated == journal.evaluated
+# the tables go up once, replicated over both devices, and a sub-space
+# gives the one-device reference's plan
+from repro.core.cutpoint import CutpointEngine
+from repro.kernels.search_pipeline import pipeline_subspace
+engine = CutpointEngine(gg, KCU1500, engine="pipeline:lax")
+dims = [len(r) for r in engine.runs]
+got, _ = pipeline_subspace(engine, (), dims, "dram", variant="lax")
+want, _ = pipeline_subspace(engine, (), dims, "dram", variant="reference")
+assert got.cuts == want.cuts and got.dram_total == want.dram_total
+[(ndev, (mesh, placement, tables))] = engine._pipeline_operands.items()
+assert ndev == 2 and mesh.size == 2
+for a in jax.tree.leaves(tables):
+    assert a.sharding == placement and a.sharding.is_fully_replicated
+    assert len(a.sharding.device_set) == 2
 print("SHARDED-OK", piped.evaluated)
 """
     env = dict(os.environ)

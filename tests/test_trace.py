@@ -33,6 +33,7 @@ PARENT = {"compile": None, "compile.group": "compile",
           "search.engine": "compile.search",
           "pipeline.subspace": "compile.search",
           "pipeline.tables": "pipeline.subspace",
+          "pipeline.upload": "pipeline.subspace",
           "pipeline.load": "pipeline.subspace",
           "pipeline.dispatch": "pipeline.subspace",
           "pipeline.wait": "pipeline.subspace",
@@ -74,7 +75,8 @@ def test_off_span_is_the_shared_noop_and_records_nothing():
 
 def test_off_span_site_costs_one_check(monkeypatch):
     """Off, every span the search opens costs one ``is_enabled`` call:
-    the sub-space, the tables, two a launch and the winner's re-price."""
+    the sub-space, the tables, the tables' upload to the device, two a
+    launch and the winner's re-price."""
     calls = [0]
 
     def off():
@@ -82,7 +84,7 @@ def test_off_span_site_costs_one_check(monkeypatch):
         return False
     monkeypatch.setattr(trace, "_is_enabled", off)
     _subspace(_engine())
-    assert calls[0] == 1 + 1 + 2 * LAUNCHES + 1
+    assert calls[0] == 1 + 1 + 1 + 2 * LAUNCHES + 1
 
 
 def test_profiler_records_the_spans_nested(tmp_path):
