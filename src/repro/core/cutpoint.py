@@ -110,7 +110,8 @@ from repro.core.hw import FPGAConfig
 # CompileOptions defaults; re-exported here for long-standing import sites.
 from repro.core.options import (DEFAULT_BATCH_SIZE,  # noqa: F401
                                 EXHAUSTIVE_LIMIT, CompileOptions,
-                                resolve_engine, resolve_options)
+                                jax_backend, resolve_engine,
+                                resolve_options)
 from repro.core.sram import (sram_report, sram_tables, sram_total_fast,
                              sram_total_fast_batch)
 from repro.core.timing import (latency_cycles_fast, latency_cycles_fast_batch,
@@ -990,11 +991,13 @@ class CutpointEngine:
 
 
 # ------------------------------------------------------------------ search
-# Largest cut-product space searched exhaustively; larger spaces fall back
-# to coordinate descent.  8M covers yolov2's full 7.96M-tuple space: with
-# the batched scorer one tuple costs ~30us, so the worst case is a few
-# minutes serial and scales further with ``workers`` via search_pool --
-# pass ``workers`` when compiling detector-scale graphs.
+# Spaces up to ``CompileOptions.exhaustive_limit_for()`` are searched
+# exhaustively, larger ones by coordinate descent.  On the host the default
+# 8M covers yolov2's full 7.96M-tuple space: with the batched scorer one
+# tuple costs ~30us, so the worst case is a few minutes serial and scales
+# further with ``workers`` via search_pool -- pass ``workers`` when
+# compiling detector-scale graphs.  The fused device pipeline on an
+# accelerator enumerates up to DEVICE_EXHAUSTIVE_LIMIT (core/options.py).
 # (EXHAUSTIVE_LIMIT / DEFAULT_BATCH_SIZE are re-exported from
 # core/options.py at the top of this module.)
 
@@ -1220,6 +1223,13 @@ def valid_warm_start(cuts, runs: list[list[int]]) -> tuple[int, ...] | None:
     return cuts
 
 
+def _on_accelerator(spec) -> bool:
+    """Whether an engine of this spec computes on an accelerator: it runs
+    jax, and jax's backend is not the CPU."""
+    from repro.core.search_pool import _engine_needs_jax
+    return _engine_needs_jax(spec) and jax_backend() != "cpu"
+
+
 def search(gg: GroupedGraph, hw: FPGAConfig,
            options: CompileOptions | None = None,
            *, guard=None, warm_start=None, **legacy) -> SearchResult:
@@ -1285,57 +1295,65 @@ PreemptionGuard` the pool polls for clean SIGTERM drain) and
                             path=path)
 
     ws = valid_warm_start(warm_start, runs)
-    if space <= opts.exhaustive_limit:
-        if space > 1_000_000 and not opts.prune:
-            warnings.warn(
-                f"exhaustive cut search over {space} tuples on a single "
-                f"core (~{space / 40_000 / 60:.0f} min); pass "
-                f"CompileOptions(workers=N) to search()/compile_graph() "
-                f"for a bit-identical result in 1/N the time, or lower "
-                f"exhaustive_limit to fall back to coordinate descent",
-                RuntimeWarning, stacklevel=2)
-        # Warm start: price the cached cuts through the direct oracle
-        # (not the engine, so ``evaluations`` bookkeeping is untouched)
-        # and open branch-and-bound with that real candidate's key as
-        # the incumbent.  Admissibility + strict-> pruning guarantee the
-        # argmin still survives, so the result is bit-identical to a
-        # cold search -- the warm start only prunes more, earlier.
-        incumbent = None
-        if ws is not None and opts.prune:
-            incumbent = _key(evaluate(gg, blocks, runs, ws, hw), objective)
-        # product order: the last run varies fastest, so consecutive tuples
-        # share the longest possible checkpoint prefix; with prune=True
-        # whole sub-spaces fall to the incumbent bound instead of being
-        # walked at all.  The pipeline engine instead fuses the whole loop
-        # on device (sharded over accelerators when more than one is
-        # visible) -- see run_subspace / kernels/search_pipeline.py.
-        best, pruned = engine.run_subspace(
-            (), [len(r) for r in runs], objective,
-            batch_size=batch_size, incumbent_key=incumbent,
-            prune=opts.prune)
-        # never all-pruned: any external incumbent is a candidate *inside*
-        # this space, whose own subtree no admissible bound can eliminate
-        assert best is not None
-        return materialize(best, pruned)
+    if space <= opts.exhaustive_limit_for():
+        with span("search.exhaustive"):
+            if (space > 1_000_000 and not opts.prune
+                    and not _on_accelerator(spec)):
+                warnings.warn(
+                    f"exhaustive cut search over {space} tuples on one "
+                    f"host core (~{space / 40_000 / 60:.0f} min); pass "
+                    f"CompileOptions(workers=N) to search()/"
+                    f"compile_graph() for a bit-identical result in 1/N "
+                    f"the time, or lower exhaustive_limit to fall back to "
+                    f"coordinate descent", RuntimeWarning, stacklevel=2)
+            # Warm start: price the cached cuts through the direct
+            # oracle (not the engine, so ``evaluations`` bookkeeping is
+            # untouched) and open branch-and-bound with that real
+            # candidate's key as the incumbent.  Admissibility + strict->
+            # pruning guarantee the argmin still survives, so the result
+            # is bit-identical to a cold search -- the warm start only
+            # prunes more, earlier.
+            incumbent = None
+            if ws is not None and opts.prune:
+                incumbent = _key(evaluate(gg, blocks, runs, ws, hw),
+                                 objective)
+            # product order: the last run varies fastest, so consecutive
+            # tuples share the longest possible checkpoint prefix; with
+            # prune=True whole sub-spaces fall to the incumbent bound
+            # instead of being walked at all.  The pipeline engine
+            # instead fuses the whole loop on device (sharded over
+            # accelerators when more than one is visible) -- see
+            # run_subspace / kernels/search_pipeline.py.
+            best, pruned = engine.run_subspace(
+                (), [len(r) for r in runs], objective,
+                batch_size=batch_size, incumbent_key=incumbent,
+                prune=opts.prune)
+            # never all-pruned: any external incumbent is a candidate
+            # *inside* this space, whose own subtree no admissible bound
+            # can eliminate
+            assert best is not None
+            return materialize(best, pruned)
 
-    # Coordinate descent with deterministic restarts (descent_starts).
-    # Move order matches the seed implementation exactly (same trajectory,
-    # same answer); the engine's memo absorbs the tuples revisited across
-    # sweeps and restarts, and trials for a given run reuse the shared
-    # allocation prefix of all earlier runs.
-    starts = descent_starts(blocks, runs)
-    if ws is not None and ws not in starts:
-        starts.append(ws)           # appended: ties still favor the cold
-        #                             starts, a warm start only ever wins
-        #                             by a strictly better key
-    best = None
-    for start in starts:
-        cur = coordinate_descent(engine, start, objective,
-                                 batch_size=batch_size)
-        if best is None or _key(cur, objective) < _key(best, objective):
-            best = cur
-    assert best is not None
-    return materialize(best, path="descent")
+    with span("search.descent"):
+        # Coordinate descent with deterministic restarts
+        # (descent_starts).  Move order matches the seed implementation
+        # exactly (same trajectory, same answer); the engine's memo
+        # absorbs the tuples revisited across sweeps and restarts, and
+        # trials for a given run reuse the shared allocation prefix of
+        # all earlier runs.
+        starts = descent_starts(blocks, runs)
+        if ws is not None and ws not in starts:
+            starts.append(ws)       # appended: ties still favor the cold
+            #                         starts, a warm start only ever wins
+            #                         by a strictly better key
+        best = None
+        for start in starts:
+            cur = coordinate_descent(engine, start, objective,
+                                     batch_size=batch_size)
+            if best is None or _key(cur, objective) < _key(best, objective):
+                best = cur
+        assert best is not None
+        return materialize(best, path="descent")
 
 
 def sweep_single_cut(gg: GroupedGraph, hw: FPGAConfig) -> list[Candidate]:

@@ -37,7 +37,13 @@ Plan-affecting (feed ``plan_key()`` and the service cache hash):
     (guaranteed optimum); beyond it coordinate descent with
     deterministic restarts runs instead.  Changing the limit can move a
     graph across that boundary and change the argmin, so it is
-    plan-affecting.
+    plan-affecting.  ``None`` (default) resolves from what the search
+    runs on (:meth:`CompileOptions.exhaustive_limit_for`):
+    ``DEVICE_EXHAUSTIVE_LIMIT`` for the fused device pipeline
+    (``pipeline:lax``) when jax's backend is an accelerator,
+    ``EXHAUSTIVE_LIMIT`` otherwise.  ``plan_key()`` carries the resolved
+    number, so a descent plan made on a CPU host and an exact plan made
+    on an accelerator never share a cache record or a journal.
 ``backend``
     ``CutpointEngine`` scoring backend: ``"numpy"`` (default,
     oracle-exact) or ``"pallas"`` (staged float32 on-device batch
@@ -91,7 +97,9 @@ Scheduling-only (wall clock / resilience / post-checks; excluded from
     ``@N`` appended to any spelling overrides ``batch_size`` for that
     engine (``"pipeline@4096"``).  The float32 Pallas *scoring* kernel
     is NOT an engine value: it changes plan bytes, so it stays on the
-    plan-affecting ``backend`` field.
+    plan-affecting ``backend`` field.  With ``exhaustive_limit`` left at
+    its default, the engine and the platform choose the limit it
+    resolves to; ``plan_key()`` carries that number, not the engine.
 ``max_retries``
     Re-dispatch budget per parallel task for *transient* failures (a
     dead worker process, an injected ChaosError, a straggler
@@ -121,9 +129,14 @@ import os
 import warnings
 from dataclasses import dataclass
 
-# Cut-product spaces up to this size are enumerated exhaustively; the
-# yolov2 detector's full 7.96M-tuple space fits (paper-scale exactness).
+# Cut-product spaces up to this size are enumerated exhaustively on the
+# host (~40,000 tuples/s a core); the yolov2 detector's full 7.96M-tuple
+# space fits (paper-scale exactness).
 EXHAUSTIVE_LIMIT = 8_000_000
+# The same for the fused device pipeline on an accelerator: 2**25 tuples
+# take about a minute on one TPU v5e chip, and mobilenet-v3@224's 15.1M
+# fit.
+DEVICE_EXHAUSTIVE_LIMIT = 2 ** 25
 
 # Cut tuples scored per ``CutpointEngine.score_batch`` call in the search
 # loops.  Large enough to amortize the numpy dispatch overhead of the 2-D
@@ -228,6 +241,12 @@ def resolve_engine(engine: str,
     return EngineSpec(name=name, variant=variant, batch_size=batch)
 
 
+def jax_backend() -> str:
+    """The platform jax computes on (``"cpu"``, ``"tpu"``, ``"gpu"``)."""
+    import jax
+    return jax.default_backend()
+
+
 def degrade_engine(engine: str) -> str:
     """The safe fallback spelling for ``engine``: the journal replay,
     preserving any explicit ``@batch`` suffix.
@@ -283,7 +302,7 @@ class CompileOptions:
     """
 
     objective: str = "latency"
-    exhaustive_limit: int = EXHAUSTIVE_LIMIT
+    exhaustive_limit: int | None = None
     workers: int | None = 1
     batch_size: int = DEFAULT_BATCH_SIZE
     engine: str = "journal"
@@ -306,7 +325,7 @@ class CompileOptions:
         if self.verify not in _VERIFY_MODES:
             raise ValueError(f"verify={self.verify!r}: expected one of "
                              f"{_VERIFY_MODES}")
-        if self.exhaustive_limit < 0:
+        if self.exhaustive_limit is not None and self.exhaustive_limit < 0:
             raise ValueError(f"exhaustive_limit={self.exhaustive_limit}: "
                              f"must be >= 0")
         if self.batch_size < 1:
@@ -332,8 +351,29 @@ class CompileOptions:
         overrides the ``batch_size`` field)."""
         return resolve_engine(self.engine, self.batch_size)
 
-    def plan_key(self) -> tuple:
-        """Canonical tuple of the plan-affecting fields.
+    def exhaustive_limit_for(self, platform: str | None = None) -> int:
+        """The exhaustive limit a search under these options applies.
+
+        An explicit ``exhaustive_limit`` is applied as given.  The default
+        (``None``) follows what the search runs on: the fused device
+        pipeline (``pipeline:lax``) on an accelerator enumerates
+        ``DEVICE_EXHAUSTIVE_LIMIT`` tuples in about the time a host core
+        takes for a few hundred thousand; every other engine, and the
+        pipeline on jax's CPU backend, keeps ``EXHAUSTIVE_LIMIT``.
+        ``device:*`` engines put only the allocator replay on the device
+        and keep the host limit.  ``platform`` is jax's backend, observed
+        when not given."""
+        if self.exhaustive_limit is not None:
+            return self.exhaustive_limit
+        spec = self.engine_spec()
+        if (spec.name, spec.variant) == ("pipeline", "lax") and (
+                platform or jax_backend()) != "cpu":
+            return DEVICE_EXHAUSTIVE_LIMIT
+        return EXHAUSTIVE_LIMIT
+
+    def plan_key(self, platform: str | None = None) -> tuple:
+        """Canonical tuple of the plan-affecting fields, with the
+        exhaustive limit as resolved (:meth:`exhaustive_limit_for`).
 
         This is what the service's persistent plan cache and the
         ``resume_dir`` task journals hash: two option sets with equal
@@ -342,7 +382,9 @@ class CompileOptions:
         two with different ``plan_key()`` must never share cache records
         or journals.
         """
-        return tuple((name, getattr(self, name)) for name in PLAN_FIELDS)
+        return tuple((name, self.exhaustive_limit_for(platform)
+                      if name == "exhaustive_limit" else getattr(self, name))
+                     for name in PLAN_FIELDS)
 
     def schedule(self) -> tuple:
         """Canonical tuple of the scheduling-only fields (wall clock /

@@ -128,6 +128,7 @@ from repro.core import cutpoint as _cp
 from repro.core.options import degrade_engine, resolve_engine
 from repro.runtime import chaos as _chaos
 from repro.runtime.fault_tolerance import PreemptionGuard, StragglerMonitor
+from repro.utils.trace import span
 
 # Sub-space tasks created per worker on the exhaustive path.  More tasks
 # than workers smooths the tail (tasks are equal-sized, but workers may not
@@ -734,7 +735,8 @@ class ParallelSearchDriver:
         space = 1
         for r in runs:
             space *= len(r) + 1
-        exhaustive = space <= opts.exhaustive_limit
+        limit = opts.exhaustive_limit_for()
+        exhaustive = space <= limit
         inline = _engine_needs_jax(opts.engine_spec())
         serial_ok = (self.workers <= 1 or not runs or inline
                      or (exhaustive and space < min_parallel_space))
@@ -744,50 +746,53 @@ class ParallelSearchDriver:
             # jax engines take it at any worker count: they run in this
             # process, sharded over its devices by the pipeline itself
             return _cp.search(
-                gg, hw, opts.replace(workers=1, resume_dir=None),
+                gg, hw, opts.replace(workers=1, resume_dir=None,
+                                     exhaustive_limit=limit),
                 warm_start=warm_start)
 
         if exhaustive:
             prefixes, suffix_dims = partition_space(
                 runs, self.workers * TASKS_PER_WORKER)
-            return self.run_subspaces(
-                gg, hw, prefixes, suffix_dims, opts,
-                blocks=blocks, runs=runs, warm_start=warm_start)
+            with span("search.exhaustive"):
+                return self.run_subspaces(
+                    gg, hw, prefixes, suffix_dims, opts,
+                    blocks=blocks, runs=runs, warm_start=warm_start)
 
-        starts = _cp.descent_starts(blocks, runs)
-        ws = _cp.valid_warm_start(warm_start, runs)
-        if ws is not None and ws not in starts:
-            starts.append(ws)       # extra deterministic start, appended
-            #                         so ties still favor the cold starts
-        self._searches += 1
-        token = (os.getpid(), id(self), self._searches, opts.engine)
-        payload = pickle.dumps((gg, hw), protocol=pickle.HIGHEST_PROTOCOL)
-        events: list[FaultEvent] = []
-        journal = None
-        if opts.resume_dir is not None:
-            journal = self._open_journal(opts.resume_dir, payload, opts,
-                                         "descent", tuple(starts))
-        batch_size = opts.engine_spec().batch_size
-        tasks = [(token, payload, s, opts.objective, batch_size,
-                  opts.engine, opts.backend) for s in starts]
-        results = self._run_tasks(
-            _run_descent, tasks, keys=starts, events=events,
-            journal=journal, encode=_encode_descent,
-            decode=_decode_descent, degrade=_degrade_descent,
-            inline=inline)
-        visited: set = set()
-        best = None
-        for start, (m, seen, wev) in zip(starts, results):
-            for kind, detail in wev:
-                events.append(FaultEvent(kind, task=start, detail=detail))
-            visited |= seen                 # start order; strict < as
-            if best is None or (_cp._key(m, opts.objective)
-                                < _cp._key(best, opts.objective)):
-                best = m                    # the serial loop over starts
-        cand = _cp.evaluate(gg, blocks, runs, best.cuts, hw)
-        return _cp.SearchResult(best=cand, evaluated=len(visited),
-                                runs=runs, blocks=blocks, events=events,
-                                path="descent")
+        with span("search.descent"):
+            starts = _cp.descent_starts(blocks, runs)
+            ws = _cp.valid_warm_start(warm_start, runs)
+            if ws is not None and ws not in starts:
+                starts.append(ws)       # extra deterministic start, appended
+                #                         so ties still favor the cold starts
+            self._searches += 1
+            token = (os.getpid(), id(self), self._searches, opts.engine)
+            payload = pickle.dumps((gg, hw), protocol=pickle.HIGHEST_PROTOCOL)
+            events: list[FaultEvent] = []
+            journal = None
+            if opts.resume_dir is not None:
+                journal = self._open_journal(opts.resume_dir, payload, opts,
+                                             "descent", tuple(starts))
+            batch_size = opts.engine_spec().batch_size
+            tasks = [(token, payload, s, opts.objective, batch_size,
+                      opts.engine, opts.backend) for s in starts]
+            results = self._run_tasks(
+                _run_descent, tasks, keys=starts, events=events,
+                journal=journal, encode=_encode_descent,
+                decode=_decode_descent, degrade=_degrade_descent,
+                inline=inline)
+            visited: set = set()
+            best = None
+            for start, (m, seen, wev) in zip(starts, results):
+                for kind, detail in wev:
+                    events.append(FaultEvent(kind, task=start, detail=detail))
+                visited |= seen                 # start order; strict < as
+                if best is None or (_cp._key(m, opts.objective)
+                                    < _cp._key(best, opts.objective)):
+                    best = m                    # the serial loop over starts
+            cand = _cp.evaluate(gg, blocks, runs, best.cuts, hw)
+            return _cp.SearchResult(best=cand, evaluated=len(visited),
+                                    runs=runs, blocks=blocks, events=events,
+                                    path="descent")
 
     def run_subspaces(self, gg, hw, prefixes, suffix_dims, options=None,
                       *, blocks=None, runs=None, warm_start=None,

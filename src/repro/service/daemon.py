@@ -229,7 +229,8 @@ class CompileService:
                     opts: CompileOptions):
         """Nearest cached cuts, guarded by the request's search path.
 
-        *Exhaustive-path* requests (space within ``exhaustive_limit``,
+        *Exhaustive-path* requests (space within the resolved limit,
+        ``CompileOptions.exhaustive_limit_for()``,
         ``prune`` + ``count_pruned`` on) may seed from any family donor:
         a seeded incumbent only prunes earlier, ``evaluated`` stays the
         full enumeration count and the argmin is oracle-exact, so the
@@ -251,7 +252,7 @@ class CompileService:
         space = 1
         for r in monotone_runs(split_blocks(group_nodes(graph))):
             space *= len(r) + 1
-        if space > opts.exhaustive_limit:
+        if space > opts.exhaustive_limit_for():
             return self.cache.nearest(fp, hw_sig,
                                       require_path="exhaustive")
         return self.cache.nearest(fp, hw_sig)

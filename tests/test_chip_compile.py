@@ -142,3 +142,23 @@ def test_sharded_fused_step_compiles_on_four_chips(mesh4):
         compiled = _shard_fused(fused, mesh4).lower(
             los, *_shapes(args, NamedSharding(mesh4, P()))).compile()
     assert compiled.output_shardings.num_devices == 4
+
+
+def test_fused_mobilenet_v3_step_compiles(one_chip):
+    """The step of an exact mobilenet-v3@224 compile on the chip: the
+    whole 15,116,544-tuple space (2^25 admits it), G = 72 with SE and
+    residual operands."""
+    from repro.kernels.search_pipeline import (_engine_tables, _lax_args,
+                                               _make_fused, _space_strides)
+    engine = _engine("mobilenet-v3", 224)
+    tbl = _engine_tables(engine)
+    dims = tuple(len(r) + 1 for r in engine.runs)
+    S = int(np.prod(dims))
+    assert (engine._at.n, S) == (72, 15_116_544)
+    with jax.enable_x64(True):
+        fused = _make_fused(tbl, CHUNK, 0, dims, _space_strides(dims), S,
+                            "sram")
+        lo = jax.ShapeDtypeStruct((), np.int32, sharding=one_chip)
+        compiled = jax.jit(fused).lower(
+            lo, *_shapes(_lax_args(tbl, ()), one_chip)).compile()
+    assert "f64" in compiled.as_text()

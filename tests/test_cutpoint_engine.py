@@ -74,10 +74,11 @@ def test_engine_repeated_unmemoized_tuple():
 
 
 # Seed search() outputs, recorded from the direct (pre-engine)
-# implementation at PR 1.  The engine must reproduce them exactly:
-# resnet50/resnet152 exercise the exhaustive path on a ResNet-style graph,
-# efficientnet-b1/mobilenet-v3 the coordinate-descent fallback on SE-style
-# graphs.
+# implementation at PR 1.  The engine must reproduce them exactly, on the
+# CPU with default options: resnet50/resnet152 exercise the exhaustive path
+# on a ResNet-style graph, efficientnet-b1/mobilenet-v3 the
+# coordinate-descent fallback on SE-style graphs (on an accelerator the
+# fused pipeline searches mobilenet-v3 exhaustively).
 SEED_RESULTS = {
     ("resnet50", 224): dict(
         cuts=(5, 0, 2, 0, 2, 0, 1, 0), latency_cycles=2163251.1999999993,

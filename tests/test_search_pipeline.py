@@ -328,9 +328,10 @@ def test_search_pipeline_parallel_matches_serial_journal():
 
 
 def test_search_pipeline_descent_path_matches_journal():
-    """Beyond exhaustive_limit the pipeline engine's search falls back to
-    the host-driven coordinate descent (score_batch under the journal
-    replay) -- results and path must match the journal engine exactly."""
+    """On the CPU, beyond the exhaustive limit, the pipeline engine's
+    search falls back to the host-driven coordinate descent (score_batch
+    under the journal replay) -- results and path must match the journal
+    engine exactly."""
     gg = group_nodes(build_cnn("mobilenet-v3"))
     journal = search(gg, KCU1500, TEST_OPTS)
     piped = search(gg, KCU1500, TEST_OPTS.replace(engine="pipeline"))

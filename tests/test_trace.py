@@ -31,7 +31,8 @@ PARENT = {"compile": None, "compile.group": "compile",
           "compile.search": "compile", "compile.materialise": "compile",
           "compile.codegen": "compile", "compile.verify": "compile",
           "search.engine": "compile.search",
-          "pipeline.subspace": "compile.search",
+          "search.exhaustive": "compile.search",
+          "pipeline.subspace": "search.exhaustive",
           "pipeline.tables": "pipeline.subspace",
           "pipeline.upload": "pipeline.subspace",
           "pipeline.load": "pipeline.subspace",
@@ -87,6 +88,22 @@ def test_off_span_site_costs_one_check(monkeypatch):
     assert calls[0] == 1 + 1 + 1 + 2 * LAUNCHES + 1
 
 
+def test_off_compile_costs_one_check_a_span_site(monkeypatch):
+    """Off, a whole compile, its search path's span included, costs one
+    ``is_enabled`` call a span site and records nothing."""
+    calls = [0]
+
+    def off():
+        calls[0] += 1
+        return False
+    monkeypatch.setattr(trace, "_is_enabled", off)
+    plan = _compile()
+    assert plan.search.path == "exhaustive"
+    per_launch = {"pipeline.load", "pipeline.dispatch", "pipeline.wait"}
+    assert calls[0] == len(set(PARENT) - per_launch) + 2 * LAUNCHES
+    assert trace.records() == []
+
+
 def test_profiler_records_the_spans_nested(tmp_path):
     with jax.profiler.trace(str(tmp_path)):
         plan = _compile()
@@ -111,6 +128,21 @@ def test_profiler_records_the_spans_nested(tmp_path):
             p = by[r.parent]
             assert p.start_ns <= r.start_ns <= r.end_ns <= p.end_ns
     _matches_profile(recs, tmp_path)
+
+
+def test_a_descent_compile_records_its_path(tmp_path):
+    """mobilenet-v3@224's 15.1M tuples exceed the exhaustive limit the
+    pipeline resolves to on jax's CPU backend: the traced compile opens
+    ``search.descent`` under ``compile.search``, and no exhaustive
+    path."""
+    with jax.profiler.trace(str(tmp_path)):
+        plan = compile_graph(build_cnn("mobilenet-v3", 224), KCU1500,
+                             CompileOptions(engine="pipeline:lax"))
+    assert plan.search.path == "descent"
+    names = [(r.name, r.parent) for r in trace.records()]
+    assert ("search.descent", "compile.search") in names
+    assert not any(n.startswith(("search.exhaustive", "pipeline."))
+                   for n, _ in names)
 
 
 def _matches_profile(recs, logdir: Path):
