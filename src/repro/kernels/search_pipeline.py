@@ -486,27 +486,32 @@ def _run_lax(engine, tbl, prefix, dims, strides, S, chunk, objective):
         name = "pipeline.dispatch"
     jfused, sharded = calls
     args = (jax.device_put(pref, placement),) + tables
-    best = None
-    if sharded is not None:
-        step = chunk * ndev
-        for base in range(0, S, step):
-            los = base + np.arange(ndev, dtype=np.int32) * chunk
-            with span(name):
-                out = sharded(los, *args)
-            name = "pipeline.dispatch"
-            with span("pipeline.wait"):
-                # rebinding frees the device result before the next launch
-                out = np.asarray(out)
-            for row in out:
-                best = _fold(best, row)
+    if sharded is None:
+        call, step, offsets = jfused, chunk, np.int32(0)
     else:
-        for lo in range(0, S, chunk):
-            with span(name):
-                out = jfused(np.int32(lo), *args)
-            name = "pipeline.dispatch"
-            with span("pipeline.wait"):
-                out = np.asarray(out)
-            best = _fold(best, out)
+        # device i scores the chunk at base + i * chunk
+        call, step = sharded, chunk * ndev
+        offsets = np.arange(ndev, dtype=np.int32) * chunk
+    # Pipelined: every launch of the sub-space is enqueued, its winner
+    # row's copy to the host started, before any row is waited for, so
+    # the device runs the steps back to back.
+    outs = []
+    for base in range(0, S, step):
+        with span(name):
+            out = call(base + offsets, *args)
+            # a result that is already a host array has nothing to copy
+            copy = getattr(out, "copy_to_host_async", None)
+            if copy is not None:
+                copy()
+        name = "pipeline.dispatch"
+        outs.append(out)
+    best = None
+    for out in outs:
+        with span("pipeline.wait"):
+            rows = np.asarray(out)
+        # one row a device, folded in launch order
+        for row in np.atleast_2d(rows):
+            best = _fold(best, row)
     return best
 
 

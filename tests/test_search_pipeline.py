@@ -430,3 +430,135 @@ def test_parallel_pipeline_runs_in_process():
     assert_results_identical(journal, whole, ctx="pipeline-in-process")
     assert_results_identical(journal, parts, ctx="pipeline-partitioned")
     assert not whole.events and not parts.events
+
+
+# ------------------------------------------------------ pipelined launches
+PIPELINED_CHUNK = 8       # vgg16-conv@224's 1,080 tuples: 135 launches
+
+
+def _whole_space(engine):
+    """``_run_lax``'s arguments after ``engine`` for its whole space."""
+    import repro.kernels.search_pipeline as sp
+    dims = tuple(len(r) + 1 for r in engine.runs)
+    return (sp._engine_tables(engine), (), dims, sp._space_strides(dims),
+            int(np.prod(dims)), PIPELINED_CHUNK)
+
+
+@needs_jax
+@pytest.mark.parametrize("objective", OBJECTIVES)
+def test_lax_many_launches_match_reference(objective):
+    """A sub-space of 135 pipelined launches on one device gives the
+    numpy reference's winner row."""
+    import jax
+    import repro.kernels.search_pipeline as sp
+    engine, _ = _engine("vgg16-conv")
+    space = _whole_space(engine)
+    assert space[4] // PIPELINED_CHUNK >= 100
+    want = sp._run_reference(engine, *space, objective)
+    with jax.enable_x64(True):
+        got = sp._run_lax(engine, *space, objective)
+    assert got == want, objective
+
+
+@needs_jax
+def test_lax_enqueues_every_launch_before_the_first_fold(monkeypatch):
+    """The engine's jitted step is called for every launch of the
+    sub-space, in ascending start order, before ``_fold`` sees a row;
+    ``_fold`` then gets one row a launch in launch order, the order the
+    benchmark's planted fold faults count on."""
+    import jax
+    import repro.kernels.search_pipeline as sp
+    engine, _ = _engine("vgg16-conv")
+    space = _whole_space(engine)
+    S = space[4]
+    with jax.enable_x64(True):
+        want = sp._run_lax(engine, *space, "latency")
+    [(key, (jfused, sharded))] = engine._pipeline_calls.items()
+    assert sharded is None
+    events = []
+
+    def launch(lo, *args):
+        out = jfused(lo, *args)
+        events.append(("launch", int(lo), out))
+        return out
+
+    def fold(best, row, fold=sp._fold):
+        events.append(("fold", tuple(float(x) for x in row)))
+        return fold(best, row)
+    engine._pipeline_calls[key] = (launch, sharded)
+    monkeypatch.setattr(sp, "_fold", fold)
+    with jax.enable_x64(True):
+        got = sp._run_lax(engine, *space, "latency")
+    assert got == want
+    n = len(range(0, S, PIPELINED_CHUNK))
+    assert [e[0] for e in events] == ["launch"] * n + ["fold"] * n
+    launches, folds = events[:n], events[n:]
+    assert [lo for _, lo, _ in launches] == list(range(0, S,
+                                                       PIPELINED_CHUNK))
+    assert [row for _, row in folds] == [
+        tuple(float(x) for x in np.asarray(out)) for _, _, out in launches]
+
+
+@needs_jax
+def test_lax_pipelined_sharded_two_devices():
+    """The same two checks with the step sharded over two host devices:
+    every objective's winner row is the reference's, and every step is
+    enqueued before the first fold, which then takes two rows a step
+    (device order) in step order."""
+    code = """
+import jax
+import numpy as np
+assert jax.device_count() == 2, jax.devices()
+from repro.cnn import build_cnn
+from repro.core.cutpoint import CutpointEngine
+from repro.core.grouping import group_nodes
+from repro.core.hw import KCU1500
+import repro.kernels.search_pipeline as sp
+engine = CutpointEngine(group_nodes(build_cnn("vgg16-conv", 224)), KCU1500,
+                        engine="pipeline:lax")
+tbl = sp._engine_tables(engine)
+dims = tuple(len(r) + 1 for r in engine.runs)
+strides = sp._space_strides(dims)
+S, chunk = int(np.prod(dims)), 8
+space = (tbl, (), dims, strides, S, chunk)
+for objective in sp.OBJECTIVES:
+    want = sp._run_reference(engine, *space, objective)
+    with jax.enable_x64(True):
+        got = sp._run_lax(engine, *space, objective)
+    assert got == want, (objective, got, want)
+key = ("lax", chunk, 0, dims, "latency")
+jfused, sharded = engine._pipeline_calls[key]
+events = []
+def launch(los, *args):
+    out = sharded(los, *args)
+    events.append(("launch", [int(x) for x in los], out))
+    return out
+def fold(best, row, fold=sp._fold):
+    events.append(("fold", tuple(float(x) for x in row)))
+    return fold(best, row)
+engine._pipeline_calls[key] = (jfused, launch)
+sp._fold = fold
+with jax.enable_x64(True):
+    got = sp._run_lax(engine, *space, "latency")
+steps = len(range(0, S, 2 * chunk))
+assert steps >= 50
+assert [e[0] for e in events] == ["launch"] * steps + ["fold"] * (2 * steps)
+assert [e[1] for e in events[:steps]] == [[b, b + chunk]
+                                          for b in range(0, S, 2 * chunk)]
+rows = [tuple(float(x) for x in r) for e in events[:steps]
+        for r in np.asarray(e[2])]
+assert [e[1] for e in events[steps:]] == rows
+print("PIPELINED-SHARDED-OK", steps)
+"""
+    env = dict(os.environ)
+    kept = [f for f in env.get("XLA_FLAGS", "").split()
+            if "xla_force_host_platform_device_count" not in f]
+    env["XLA_FLAGS"] = " ".join(
+        kept + ["--xla_force_host_platform_device_count=2"])
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(p) for p in sys.path if p] + [env.get("PYTHONPATH", "")])
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, (out.stdout, out.stderr)
+    assert "PIPELINED-SHARDED-OK" in out.stdout, out.stdout
